@@ -58,6 +58,8 @@ def main(argv=None) -> None:
     ap.add_argument("--modules", default=None,
                     help="comma-separated subset of benchmark modules")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (accuracy, adaptivity, cascade, compaction,
                             kernels, multi_query, parallelism, pruning,

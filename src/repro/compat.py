@@ -1,55 +1,31 @@
-"""Version-compatibility shims over the moving parts of the jax API.
+"""Thin wrappers over the JAX mesh and ``shard_map`` API.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` (where the
-replication-check kwarg is ``check_rep``) to ``jax.shard_map`` (where it is
-``check_vma``). Callers use :func:`shard_map` below with the version-neutral
-``check_replication`` kwarg. Similarly ``jax.make_mesh`` grew an
-``axis_types`` kwarg and the ambient mesh moved from ``with mesh:`` to
-``jax.set_mesh`` — :func:`make_mesh` / :func:`set_mesh` paper over both.
+Callers pass the replication-check flag as ``check_replication`` and get
+meshes whose axes are all ``Auto``, so sharding stays propagated by the
+compiler unless a ``with_sharding_constraint`` says otherwise.
 """
 from __future__ import annotations
 
 import jax
 
-try:                                          # jax >= 0.6
-    from jax import shard_map as _shard_map
-    _REPL_KW = "check_vma"
-except ImportError:                           # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REPL_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_REPL_KW: check_replication})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_replication)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """Device mesh with Auto axis types where the jax version supports them."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names)
+    """Device mesh with ``Auto`` axis types."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
 
 
 def set_mesh(mesh):
-    """Ambient-mesh context manager: ``jax.set_mesh`` or ``with mesh:``."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh                               # jax 0.4.x: Mesh is a CM
+    """Ambient-mesh context manager."""
+    return jax.set_mesh(mesh)
 
 
 def get_ambient_mesh():
-    """The mesh set by :func:`set_mesh` (or None). Both concrete and abstract
-    meshes expose ``axis_names`` / ``axis_sizes``, which is all callers use.
-
-    Branches on the same probe as :func:`set_mesh` — on versions where
-    ``set_mesh`` falls back to ``with mesh:`` the mesh lands in the
-    thread-local physical slot, not the abstract one, and must be read back
-    from there."""
-    if hasattr(jax, "set_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The abstract mesh set by :func:`set_mesh`; its ``axis_names`` are
+    empty when no mesh is set."""
+    return jax.sharding.get_abstract_mesh()

@@ -39,6 +39,7 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.core import temporal as temporal_lib
 from repro.core.query import Triple, VMRQuery
+from repro.kernels.topk_similarity import K_PAD
 
 
 def pow2_bucket(n: int, minimum: int = 4) -> int:
@@ -60,8 +61,12 @@ def predicted_search_bytes(mode: str, capacity: int, dim: int,
     fp32 rows per query for the exact rescore (k′ = min(4k, 128), the
     kernel's overfetch — see ``repro.kernels.topk_similarity_i8``); the
     int4 cold-tier path reads nibble-packed codes (dim/2 bytes per row,
-    ~0.125× the fp32 scan) with a wider k′ = min(8k, 128) overfetch.
+    ~0.125× the fp32 scan) with a wider k′ = min(8k, 128) overfetch. A
+    top-k wider than the kernels' 128 scans fp32 in every mode
+    (``repro.semantic.search.range_mode``).
     """
+    from repro.semantic.search import range_mode
+    mode = range_mode(mode, k)
     out = n_texts * k * 8                        # (scores, idx) results
     if mode == "int8":
         kprime = min(4 * k, 128)
@@ -94,10 +99,11 @@ def predicted_search_bytes_tiered(mode: str, stores, dim: int,
         return predicted_search_bytes(mode, stores.entities.capacity, dim,
                                       n_texts, k)
     from repro.core.stores import entity_search_bounds
+    from repro.semantic.search import range_mode
     total = n_texts * k * 8                      # (scores, idx) results
     for (start, stop), tier in zip(entity_search_bounds(stores), tiers):
-        m = "int4" if tier == "cold" else mode
         cap = stop - start
+        m = range_mode("int4" if tier == "cold" else mode, min(k, cap))
         if m == "int8":
             total += (cap * (dim + 8)
                       + n_texts * min(4 * k, 128) * dim * 4)
@@ -123,7 +129,10 @@ class EntityMatch:
     ``search_mode`` is the engine's scan precision (``"fp32"`` brute force
     or ``"int8"`` two-phase with exact rescore) and ``predicted_bytes`` the
     plan-time model of HBM bytes the search launches will move — both are
-    EXPLAIN artifacts (``Session.explain``).
+    EXPLAIN artifacts (``Session.explain``). ``fp32_ranges`` counts the
+    search ranges whose top-``min(k, rows)`` is wider than the kernels'
+    128 columns: those scan fp32 through the jnp reference whatever the
+    mode or tier (``repro.semantic.search.range_mode``).
     """
 
     names: Tuple[str, ...]
@@ -135,6 +144,7 @@ class EntityMatch:
     image_threshold: float
     search_mode: str = "fp32"
     predicted_bytes: int = 0    # modeled HBM traffic of the search launches
+    fp32_ranges: int = 0        # ranges too wide for the top-k kernels
 
     @property
     def width(self) -> int:
@@ -150,6 +160,9 @@ class EntityMatch:
         out = [head,
                f"  search_mode={self.search_mode} "
                f"predicted_bytes={self.predicted_bytes:,}"]
+        if self.fp32_ranges:
+            out.append(f"  {self.fp32_ranges} range(s) scan fp32 in jnp: "
+                       f"top-k over {K_PAD}, the kernels' width")
         for name, row in zip(self.names, self.rows):
             out.append(f"  {name} ~ {self.texts[row]!r}")
         return out
@@ -375,6 +388,9 @@ def compile_plan(query: VMRQuery, stores, *, verify: bool,
         pred_bytes += predicted_search_bytes_tiered(search_mode, stores,
                                                     dims[1], len(ent_texts),
                                                     k_ent)
+    from repro.core.stores import entity_search_bounds
+    fp32_ranges = sum(min(k_ent, stop - start) > K_PAD
+                      for start, stop in entity_search_bounds(stores))
     em = EntityMatch(
         names=tuple(e.name for e in query.entities),
         texts=ent_texts, rows=ent_rows,
@@ -383,7 +399,8 @@ def compile_plan(query: VMRQuery, stores, *, verify: bool,
         image_search=query.image_search,
         image_threshold=query.image_threshold,
         search_mode=search_mode,
-        predicted_bytes=pred_bytes)
+        predicted_bytes=pred_bytes,
+        fp32_ranges=fp32_ranges)
     pm = PredicateMatch(
         names=tuple(r.name for r in query.relationships),
         texts=rel_texts, rows=rel_rows,

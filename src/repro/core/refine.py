@@ -93,34 +93,42 @@ class VLMVerifier:
         return f"question is the {sdesc} {PREDICATES[rl]} the {odesc} answer"
 
     def verify(self, rows: np.ndarray) -> np.ndarray:
-        """rows: (M, 5) -> bool (M,). Pads to batch_size multiples."""
+        """rows: (M, 5) -> bool (M,): the yes-minus-no margin is positive."""
+        self.calls += len(rows)
+        return self.margins(rows) > 0
+
+    def margins(self, rows: np.ndarray) -> np.ndarray:
+        """rows: (M, 5) -> fp32 (M,) last-position logit of "yes" minus
+        that of "no". Pads to batch_size multiples."""
         m = len(rows)
-        if m == 0:
-            return np.zeros((0,), bool)
-        self.calls += m
+        out = np.zeros((m,), np.float32)
+        bs = self.batch_size
+        for lo in range(0, m, bs):
+            chunk = rows[lo: lo + bs]
+            scores = np.asarray(self._scores(self.params,
+                                             *self.batch_inputs(chunk)))
+            out[lo: lo + len(chunk)] = scores[: len(chunk)]
+        return out
+
+    def batch_inputs(self, chunk: np.ndarray):
+        """(tokens, patches, mrope_positions) for at most ``batch_size``
+        candidate rows, padded to a full batch."""
         cfg = self.cfg
         P, D = cfg.vision.num_positions, cfg.vision.embed_dim
         bs = self.batch_size
-        out = np.zeros((m,), bool)
-        for lo in range(0, m, bs):
-            chunk = rows[lo: lo + bs]
-            pad = bs - len(chunk)
-            toks, patches = [], []
-            for (vid, fid, sid, rl, oid) in chunk:
-                ids, _ = self.tokenizer.encode(
-                    self._prompt(int(vid), int(sid), int(rl), int(oid)),
-                    self.prompt_len)
-                toks.append(ids)
-                patches.append(self.world.frame_patches(int(vid), int(fid),
-                                                        P, D))
-            for _ in range(pad):
-                toks.append(np.zeros((self.prompt_len,), np.int32))
-                patches.append(np.zeros((P, D), np.float32))
-            tokens = jnp.asarray(np.stack(toks))
-            patch = jnp.asarray(np.stack(patches), jnp.bfloat16)
-            S = self._seq_len
-            mrope = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, None],
-                                     (3, bs, S))
-            scores = np.asarray(self._scores(self.params, tokens, patch, mrope))
-            out[lo: lo + len(chunk)] = scores[: len(chunk)] > 0
-        return out
+        toks, patches = [], []
+        for (vid, fid, sid, rl, oid) in chunk:
+            ids, _ = self.tokenizer.encode(
+                self._prompt(int(vid), int(sid), int(rl), int(oid)),
+                self.prompt_len)
+            toks.append(ids)
+            patches.append(self.world.frame_patches(int(vid), int(fid), P, D))
+        for _ in range(bs - len(chunk)):
+            toks.append(np.zeros((self.prompt_len,), np.int32))
+            patches.append(np.zeros((P, D), np.float32))
+        tokens = jnp.asarray(np.stack(toks))
+        patch = jnp.asarray(np.stack(patches), jnp.bfloat16)
+        S = self._seq_len
+        mrope = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, None],
+                                 (3, bs, S))
+        return tokens, patch, mrope

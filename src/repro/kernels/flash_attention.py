@@ -8,7 +8,9 @@ h // group). Causal / sliding-window / chunked-local masking comes from the
 position operands, so ragged (non-arange) positions also work.
 
 Block shapes: q rows ``blk_q`` (default 256), kv rows ``blk_k`` (default 512),
-head_dim lanes — all MXU-aligned for head_dim ∈ {64, 128, 160}.
+head_dim lanes. The kernel sees (B, H, S, D): Mosaic tiles the last two
+block dims, which must be multiples of (8, 128) or span the array, so a
+(1, blk, 1, D) block over the model's (B, S, H, D) layout does not compile.
 VMEM working set ≈ blk_q·D (q) + 2·blk_k·D (k,v) + blk_q·blk_k (scores) +
 blk_q·D (acc) floats ≈ 1.1 MB at defaults — comfortably under the ~16 MB/core
 budget, leaving room for double buffering.
@@ -36,14 +38,14 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (blk_q, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (blk_k, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                # (blk_q, D)
+    k = k_ref[0, 0].astype(jnp.float32)                # (blk_k, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qp = qpos_ref[0, :].astype(jnp.int32)[:, None]     # (blk_q, 1)
-    kp = kpos_ref[0, :].astype(jnp.int32)[None, :]     # (1, blk_k)
+    qp = qpos_ref[0].astype(jnp.int32).T                # (blk_q, 1)
+    kp = kpos_ref[0].astype(jnp.int32)                  # (1, blk_k)
     ok = kp < jnp.int32(2**30)        # padded kv rows are always invalid
     ok = jnp.broadcast_to(ok, s.shape)
     if causal:
@@ -67,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
     @pl.when(j == n_kv_blocks - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -94,6 +96,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Sq_p, Skv_p = Sq + pad_q, Skv + pad_k
     nQ, nK = Sq_p // blk_q, Skv_p // blk_k
 
+    # Mosaic tiles the last two block dims, which must be multiples of
+    # (8, 128) or span the array: so heads go ahead of the sequence, and
+    # each position row gets a unit axis ahead of it.
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    q_pos, kv_pos = q_pos[:, None, :], kv_pos[:, None, :]
     grid = (B, Hq, nQ, nK)
     kern = functools.partial(
         _kernel, scale=D ** -0.5, causal=causal, window=window, chunk=chunk,
@@ -102,15 +109,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, blk_k, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, blk_k), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((1, 1, blk_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, blk_q), lambda b, h, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, blk_k), lambda b, h, i, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, D),
-                               lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, Hq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, blk_q, D),
+                               lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),   # m
             pltpu.VMEM((blk_q, 1), jnp.float32),   # l
@@ -118,4 +125,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         interpret=interpret,
     )(q, k, v, q_pos, kv_pos)
-    return out[:, :Sq]
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

@@ -54,8 +54,17 @@ def decode_attention(q, k_cache, v_cache, kv_valid, cfg: ModelConfig):
     return o.reshape(B, 1, Hq, D)
 
 
+def _check_k(k: int) -> None:
+    """The top-k kernels keep a K_PAD-wide running top-k in VMEM scratch;
+    a larger k is refused rather than silently served by the oracle."""
+    if k > _topk.K_PAD:
+        raise ValueError(f"top-k kernels hold at most {_topk.K_PAD} results "
+                         f"per query, got k={k}")
+
+
 def topk_similarity(queries, db, db_valid, k: int):
-    if _force_ref() or k > _topk.K_PAD:
+    _check_k(k)
+    if _force_ref():
         return _ref.naive_topk(queries, db, db_valid, k)
     return _topk.topk_similarity(queries, db, db_valid, k,
                                  interpret=_interpret())
@@ -68,8 +77,7 @@ def topk_similarity_i8(queries, db_i8, db, db_valid, k: int):
     Pallas kernel — the two-phase result stays exact either way (the
     margin check certifies the candidate set, however it was produced).
     """
-    if k > _topk.K_PAD:
-        return _ref.naive_topk(queries, db, db_valid, k)
+    _check_k(k)
     return _topk_i8.topk_similarity_i8(
         queries, db_i8, db, db_valid, k, interpret=_interpret(),
         use_kernel_phase1=not _force_ref())
@@ -83,8 +91,7 @@ def topk_similarity_i4(queries, db_i4, db, db_valid, k: int):
     the margin certificate (or fp32 fallback) covers the candidate set
     however it was produced.
     """
-    if k > _topk.K_PAD:
-        return _ref.naive_topk(queries, db, db_valid, k)
+    _check_k(k)
     return _topk_i4.topk_similarity_i4(
         queries, db_i4, db, db_valid, k, interpret=_interpret(),
         use_kernel_phase1=not _force_ref())

@@ -52,7 +52,8 @@ def naive_decode_attention(q, k_cache, v_cache, kv_valid) -> jax.Array:
 def naive_topk(queries, db, db_valid, k: int) -> Tuple[jax.Array, jax.Array]:
     """queries (Q,D), db (N,D) -> (scores, idx) each (Q,k)."""
     s = jnp.einsum("qd,nd->qn", queries.astype(jnp.float32),
-                   db.astype(jnp.float32))
+                   db.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     s = jnp.where(db_valid[None, :] > 0, s, -jnp.inf)
     return jax.lax.top_k(s, k)
 

@@ -8,9 +8,10 @@ cold sweep reads N·(D/2 + 8) bytes — ~8× less than fp32, ~2× less than
 int8 — at the price of a coarser phase-1 ranking.
 
   * **Phase 1 (approximate, int4).** The Pallas kernel streams packed
-    bytes through VMEM, unpacks nibbles to int8 in-register (shift +
-    arithmetic shift sign-extension — no extra HBM traffic), forms the
-    score tile as an int8×int8→int32 MXU matmul (integer dots are exact),
+    bytes through VMEM, sign-extends each nibble in-register (two shifts,
+    no extra HBM traffic), forms the score tile as two int8×int8→int32
+    MXU matmuls, even columns against low nibbles and odd against high
+    (integer dots are exact, so the split changes no bit),
     rescales to fp32, and keeps a running over-fetched top-k′ in VMEM
     scratch. int4 ranks are noisier than int8, so the overfetch is wider:
     k′ = min(8k, 128).
@@ -38,14 +39,15 @@ nothing and would double the query-side error term).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_similarity import K_PAD, NEG_INF, _extract_topk
+from repro.kernels.topk_similarity import (K_PAD, NEG_INF, _extract_topk,
+                                           db_block_rows)
 from repro.kernels.topk_similarity_i8 import (_BOUND_SLACK, _rescore_exact,
                                               quantize_rows)
 
@@ -62,22 +64,25 @@ class Int4Rows(NamedTuple):
     term of the dot-product error bound. NamedTuple ⇒ pytree.
     """
 
-    packed: jax.Array  # (N, ceil(D/2)) uint8
+    packed: jax.Array  # (N, ceil(D/2)) int8
     scale: jax.Array   # (N,) fp32
     err: jax.Array     # (N,) fp32
 
 
 def pack_nibbles(codes: jax.Array) -> jax.Array:
-    """(N, D) int codes in [-8, 7] -> (N, ceil(D/2)) uint8, two per byte."""
+    """(N, D) int codes in [-8, 7] -> (N, ceil(D/2)) int8, two per byte.
+
+    The byte is ``odd * 16 + (even & 0xF)``, which is in int8 range, so the
+    Pallas kernel reads the bank as stored and sign-extends each nibble."""
     c = jnp.asarray(codes, jnp.int32)
     if c.shape[1] % 2:
         c = jnp.pad(c, ((0, 0), (0, 1)))
     even, odd = c[:, 0::2], c[:, 1::2]
-    return ((even & 0xF) | ((odd & 0xF) << 4)).astype(jnp.uint8)
+    return ((even & 0xF) | (odd << 4)).astype(jnp.int8)
 
 
 def unpack_nibbles(packed: jax.Array) -> jax.Array:
-    """(N, D2) uint8 -> (N, 2*D2) int8 codes, sign-extended nibbles."""
+    """(N, D2) int8 -> (N, 2*D2) int8 codes, sign-extended nibbles."""
     p = packed.astype(jnp.int32)
     low = jnp.right_shift(jnp.left_shift(p, 28), 28)    # arithmetic >> 28
     high = jnp.right_shift(jnp.left_shift(p, 24), 28)
@@ -105,8 +110,9 @@ def dequantize_rows_i4(rows: Int4Rows, d: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 # phase 1: packed-int4 streaming approximate top-k' (Pallas)
 # ---------------------------------------------------------------------------
-def _kernel_i4(q_ref, tq_ref, db_ref, s_ref, valid_ref, sout_ref, iout_ref,
-               best_s, best_i, *, kprime: int, blk_n: int, n_db_blocks: int):
+def _kernel_i4(qe_ref, qo_ref, tq_ref, db_ref, s_ref, valid_ref, sout_ref,
+               iout_ref, best_s, best_i, *, kprime: int, blk_n: int,
+               n_db_blocks: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -114,12 +120,20 @@ def _kernel_i4(q_ref, tq_ref, db_ref, s_ref, valid_ref, sout_ref, iout_ref,
         best_s[...] = jnp.full_like(best_s, NEG_INF)
         best_i[...] = jnp.zeros_like(best_i)
 
-    q = q_ref[...]                                      # (blk_q, D) int8
-    db = unpack_nibbles(db_ref[...])                    # (blk_n, D) int8
-    acc = jax.lax.dot_general(q, db, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    s = (acc.astype(jnp.float32) * tq_ref[...][:, None]) * s_ref[...][None, :]
-    valid = valid_ref[...][None, :] > 0
+    # each nibble of the packed int8 bytes is sign-extended with two shifts. The even and odd columns
+    # stay apart (q·d = q_even·low + q_odd·high): re-interleaving them
+    # is a lane shuffle that Mosaic does not compile in reasonable time.
+    p = db_ref[...].astype(jnp.int32)                   # (blk_n, D2)
+    low = jnp.right_shift(jnp.left_shift(p, 28), 28).astype(jnp.int8)
+    high = jnp.right_shift(jnp.left_shift(p, 24), 28).astype(jnp.int8)
+    # explicit precision, as in the int8 kernel
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.DEFAULT,
+                            preferred_element_type=jnp.int32)
+    acc = dot(qe_ref[...], low) + dot(qo_ref[...], high)
+    s = (acc.astype(jnp.float32) * tq_ref[...]) * s_ref[...]
+    valid = valid_ref[...] > 0
     s = jnp.where(valid, s, NEG_INF)
     base = j * blk_n
     gidx = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -137,7 +151,7 @@ def _kernel_i4(q_ref, tq_ref, db_ref, s_ref, valid_ref, sout_ref, iout_ref,
 
 def topk_i4_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int4Rows,
                    db_valid: jax.Array, kprime: int, *, blk_q: int = 128,
-                   blk_n: int = 1024, interpret: bool = False):
+                   blk_n: Optional[int] = None, interpret: bool = False):
     """Approximate top-k' over packed int4 codes. Returns (scores, idx)
     shaped (Q, k'), same ordering contract as the int8 phase 1."""
     assert kprime <= K_PAD, "phase-1 scratch is K_PAD columns wide"
@@ -145,15 +159,16 @@ def topk_i4_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int4Rows,
     D2 = db.packed.shape[1]
     if 2 * D2 != D:                    # odd D: phantom zero column
         q_codes = jnp.pad(q_codes, ((0, 0), (0, 2 * D2 - D)))
-        D = 2 * D2
     N = db.packed.shape[0]
     blk_q = min(blk_q, max(32, Q))
-    blk_n = min(blk_n, N)
+    # the body widens each block to two int32 (blk_n, D2) nibble planes
+    blk_n = min(blk_n, N) if blk_n else db_block_rows(N, 8 * D2)
     pad_q = (-Q) % blk_q
     pad_n = (-N) % blk_n
     if pad_q:
         q_codes = jnp.pad(q_codes, ((0, pad_q), (0, 0)))
         q_scale = jnp.pad(q_scale, ((0, pad_q),))
+    q_even, q_odd = q_codes[:, 0::2], q_codes[:, 1::2]
     packed, scale, valid = db.packed, db.scale, db_valid
     if pad_n:
         packed = jnp.pad(packed, ((0, pad_n), (0, 0)))
@@ -168,11 +183,12 @@ def topk_i4_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int4Rows,
         kern,
         grid=(nQ, nN),
         in_specs=[
-            pl.BlockSpec((blk_q, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk_q,), lambda i, j: (i,)),
+            pl.BlockSpec((blk_q, D2), lambda i, j: (i, 0)),
+            pl.BlockSpec((blk_q, D2), lambda i, j: (i, 0)),
+            pl.BlockSpec((blk_q, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((blk_n, D2), lambda i, j: (j, 0)),
-            pl.BlockSpec((blk_n,), lambda i, j: (j,)),
-            pl.BlockSpec((blk_n,), lambda i, j: (j,)),
+            pl.BlockSpec((1, blk_n), lambda i, j: (0, j)),
+            pl.BlockSpec((1, blk_n), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((blk_q, K_PAD), lambda i, j: (i, 0)),
@@ -187,7 +203,8 @@ def topk_i4_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int4Rows,
             pltpu.VMEM((blk_q, K_PAD), jnp.int32),
         ],
         interpret=interpret,
-    )(q_codes, q_scale, packed, scale, valid.astype(jnp.int32))
+    )(q_even, q_odd, q_scale[:, None], packed, scale[None, :],
+      valid.astype(jnp.int32)[None, :])
     return scores[:Q, :kprime], idx[:Q, :kprime]
 
 
@@ -212,7 +229,7 @@ def topk_i4_phase1_ref(q_codes, q_scale, db: Int4Rows, db_valid, kprime: int):
 # ---------------------------------------------------------------------------
 def topk_similarity_i4(queries: jax.Array, db_i4: Int4Rows, db: jax.Array,
                        db_valid: jax.Array, k: int, *, blk_q: int = 128,
-                       blk_n: int = 1024, interpret: bool = False,
+                       blk_n: Optional[int] = None, interpret: bool = False,
                        use_kernel_phase1: bool = True):
     """Exact two-phase cold-tier top-k. queries: (Q, D) fp32; db: (N, D)
     fp32 rows backing ``db_i4``. Returns (scores, idx): (Q, k), bitwise

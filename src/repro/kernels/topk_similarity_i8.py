@@ -46,14 +46,15 @@ semantics, see docs/performance.md).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_similarity import K_PAD, NEG_INF, _extract_topk
+from repro.kernels.topk_similarity import (K_PAD, NEG_INF, _extract_topk,
+                                           db_block_rows)
 
 OVERFETCH = 4          # k' = min(OVERFETCH * k, K_PAD)
 # fp multiply slop on the analytic bound (the bound itself is exact real
@@ -106,10 +107,13 @@ def _kernel_i8(q_ref, tq_ref, db_ref, s_ref, valid_ref, sout_ref, iout_ref,
     db = db_ref[...]                                        # (blk_n, D) int8
     # integer dot products are exact: the MXU accumulates int8 pairs in
     # int32, so phase-1 scores carry no reduction rounding at all
+    # an explicit precision: under default_matmul_precision("highest")
+    # Mosaic would be asked for an fp32 contraction of int8 operands
     acc = jax.lax.dot_general(q, db, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.DEFAULT,
                               preferred_element_type=jnp.int32)
-    s = (acc.astype(jnp.float32) * tq_ref[...][:, None]) * s_ref[...][None, :]
-    valid = valid_ref[...][None, :] > 0                     # (1, blk_n)
+    s = (acc.astype(jnp.float32) * tq_ref[...]) * s_ref[...]
+    valid = valid_ref[...] > 0                              # (1, blk_n)
     s = jnp.where(valid, s, NEG_INF)
     base = j * blk_n
     gidx = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -127,7 +131,7 @@ def _kernel_i8(q_ref, tq_ref, db_ref, s_ref, valid_ref, sout_ref, iout_ref,
 
 def topk_i8_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int8Rows,
                    db_valid: jax.Array, kprime: int, *, blk_q: int = 128,
-                   blk_n: int = 1024, interpret: bool = False):
+                   blk_n: Optional[int] = None, interpret: bool = False):
     """Approximate top-k' over int8 codes. Returns (scores, idx) (Q, k').
 
     Scores are the dequantized int32 dot products (sorted descending,
@@ -140,7 +144,7 @@ def topk_i8_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int8Rows,
     # int8 tiles want >= 32 sublanes; interpret mode doesn't care, compiled
     # mode gets a properly padded block either way
     blk_q = min(blk_q, max(32, Q))
-    blk_n = min(blk_n, N)
+    blk_n = min(blk_n, N) if blk_n else db_block_rows(N, D)
     pad_q = (-Q) % blk_q
     pad_n = (-N) % blk_n
     if pad_q:
@@ -161,10 +165,10 @@ def topk_i8_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int8Rows,
         grid=(nQ, nN),
         in_specs=[
             pl.BlockSpec((blk_q, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk_q,), lambda i, j: (i,)),
+            pl.BlockSpec((blk_q, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((blk_n, D), lambda i, j: (j, 0)),
-            pl.BlockSpec((blk_n,), lambda i, j: (j,)),
-            pl.BlockSpec((blk_n,), lambda i, j: (j,)),
+            pl.BlockSpec((1, blk_n), lambda i, j: (0, j)),
+            pl.BlockSpec((1, blk_n), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((blk_q, K_PAD), lambda i, j: (i, 0)),
@@ -179,7 +183,8 @@ def topk_i8_phase1(q_codes: jax.Array, q_scale: jax.Array, db: Int8Rows,
             pltpu.VMEM((blk_q, K_PAD), jnp.int32),
         ],
         interpret=interpret,
-    )(q_codes, q_scale, codes, scale, valid.astype(jnp.int32))
+    )(q_codes, q_scale[:, None], codes, scale[None, :],
+      valid.astype(jnp.int32)[None, :])
     return scores[:Q, :kprime], idx[:Q, :kprime]
 
 
@@ -232,7 +237,8 @@ def _rescore_exact(queries, db, cand_idx, cand_finite, k: int):
         n = _RESCORE_BLK if Q - lo >= _RESCORE_BLK + 2 else Q - lo
         flat = db[cand_idx[lo:lo + n].reshape(-1)]          # (n*kp, D)
         s_all = jnp.einsum("qd,md->qm", q32[lo:lo + n],
-                           flat.astype(jnp.float32))        # (n, n*kp)
+                           flat.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)  # (n, n*kp)
         take = (jnp.arange(n, dtype=jnp.int32)[:, None] * kp
                 + jnp.arange(kp, dtype=jnp.int32)[None, :])
         tiles.append(jnp.take_along_axis(s_all, take, axis=1))
@@ -246,7 +252,7 @@ def _rescore_exact(queries, db, cand_idx, cand_finite, k: int):
 
 def topk_similarity_i8(queries: jax.Array, db_i8: Int8Rows, db: jax.Array,
                        db_valid: jax.Array, k: int, *, blk_q: int = 128,
-                       blk_n: int = 1024, interpret: bool = False,
+                       blk_n: Optional[int] = None, interpret: bool = False,
                        use_kernel_phase1: bool = True):
     """Exact two-phase top-k. queries: (Q, D) fp32; db: (N, D) fp32 rows
     backing ``db_i8``. Returns (scores, idx): (Q, k), bit-comparable with

@@ -5,14 +5,15 @@
 Boots the full stack — synthetic world, ingest into Entity/Relationship
 stores, the query engine, the refinement verifier (mock or reduced VLM) —
 then serves a batch of randomized VMR queries and prints per-stage timings,
-pruning statistics and throughput. On TPU slices pass ``--mesh single`` to
-shard the vector store over the data axis (distributed top-k).
+pruning statistics and throughput, naming the device they ran on.
 """
 import argparse
 import time
 
+import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import LazyVLMEngine
 from repro.core.query import (Entity, FrameSpec, Relationship,
@@ -56,6 +57,8 @@ def main():
                     default="mock")
     ap.add_argument("--seed", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
 
     t0 = time.time()
     world = SyntheticWorld(WorldConfig(
@@ -92,7 +95,8 @@ def main():
     dt = time.time() - t0
     frames = args.segments * 32
     print(f"\n{args.queries} queries in {dt:.1f}s "
-          f"({args.queries / dt:.2f} qps on CPU)")
+          f"({args.queries / dt:.2f} qps on {dev.platform} "
+          f"{dev.device_kind})")
     print(f"stage seconds: { {k: round(v, 3) for k, v in stage_totals.items()} }")
     print(f"VLM saw {total_cand} candidate frames total vs "
           f"{frames * args.queries} frame-inspections an e2e VLM would do "

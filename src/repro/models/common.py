@@ -76,13 +76,9 @@ def with_sharding_constraint(x, spec):
     a dimension unevenly are dropped (so batch-1 shapes stay replicated).
     No-op without a mesh context.
     """
-    try:
-        from repro.compat import get_ambient_mesh
-        mesh = get_ambient_mesh()
-        names = dict(zip(mesh.axis_names, mesh.axis_sizes)) \
-            if mesh is not None and mesh.axis_names else {}
-    except Exception:
-        return x
+    from repro.compat import get_ambient_mesh
+    mesh = get_ambient_mesh()
+    names = dict(zip(mesh.axis_names, mesh.axis_sizes))
     if not names:
         return x
     clean = []
@@ -98,8 +94,5 @@ def with_sharding_constraint(x, spec):
                 size *= names[a]
         clean.append(tuple(kept) if len(kept) > 1 else
                      (kept[0] if kept else None))
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*clean))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*clean))

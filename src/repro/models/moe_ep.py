@@ -40,12 +40,9 @@ def ep_enabled(cfg: ModelConfig, x_shape) -> bool:
     """EP path applies when opted in and the layout divides cleanly."""
     if os.environ.get("REPRO_MOE_EP", "0") != "1":
         return False
-    try:
-        from repro.compat import get_ambient_mesh
-        am = get_ambient_mesh()
-    except Exception:
-        return False
-    if am is None or not am.axis_names or "model" not in am.axis_names:
+    from repro.compat import get_ambient_mesh
+    am = get_ambient_mesh()
+    if "model" not in am.axis_names:
         return False
     sizes = dict(zip(am.axis_names, am.axis_sizes))
     n = sizes["model"]

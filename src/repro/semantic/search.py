@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
+from repro.kernels.topk_similarity import K_PAD
 
 # "int4" is the cold-tier scan: engines select it *per segment* (via the
 # ``modes`` arguments below) when the tiered-storage layer has demoted a
@@ -33,14 +34,24 @@ from repro.compat import shard_map
 SEARCH_MODES = ("fp32", "int8", "int4")
 
 
+def range_mode(mode: str, k: int) -> str:
+    """The scan one range of top-``k`` runs: every top-k kernel holds at
+    most ``K_PAD`` results per query, so a wider top-k scans fp32 through
+    :func:`topk_similarity_ref` whatever the range's mode (EXPLAIN shows
+    it, see ``EntityMatch``). Both answers are the same exact top-k."""
+    return "fp32" if k > K_PAD else mode
+
+
 def topk_similarity_ref(queries: jax.Array, db: jax.Array, db_valid: jax.Array,
                         k: int) -> Tuple[jax.Array, jax.Array]:
     """queries: (Q, D) and db: (N, D) L2-normalized. Returns (scores, idx): (Q, k).
 
-    Invalid DB rows score -inf.
+    Invalid DB rows score -inf. The scores are fp32 dot products on every
+    backend: without ``HIGHEST`` a TPU contracts fp32 in bf16 passes.
     """
     scores = jnp.einsum("qd,nd->qn", queries.astype(jnp.float32),
-                        db.astype(jnp.float32))
+                        db.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(db_valid[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
 
@@ -48,9 +59,12 @@ def topk_similarity_ref(queries: jax.Array, db: jax.Array, db_valid: jax.Array,
 def topk_similarity(queries, db, db_valid, k: int, *, use_kernels: bool = False,
                     mode: str = "fp32", i8=None, i4=None):
     """Mode/kernel dispatch for one device. ``i8``/``i4`` are the store's
-    quantized banks backing ``db`` (required for the matching mode)."""
+    quantized banks backing ``db`` (required for the matching mode).
+    A ``k`` beyond the kernels' width scans fp32 (:func:`range_mode`)."""
     if mode not in SEARCH_MODES:
         raise ValueError(f"unknown search mode {mode!r}; one of {SEARCH_MODES}")
+    if k > K_PAD:
+        return topk_similarity_ref(queries, db, db_valid, k)
     if mode == "int8":
         if i8 is None:
             raise ValueError("mode='int8' needs the store's Int8Rows bank "

@@ -15,6 +15,7 @@ truth, so the pipeline's accuracy is actually verifiable:
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
@@ -178,9 +179,11 @@ class SyntheticWorld:
         """Deterministic 'vision encoder output' for a frame (stub frontend).
 
         Features are a function of the frame's object layout, so a trained
-        verifier could in principle read the geometry back out.
+        verifier could in principle read the geometry back out. Seeds are
+        CRC-32 digests of the frame and object fields, the same in every
+        process (Python's ``hash`` of a string is salted per process).
         """
-        rng = np.random.default_rng(hash((vid, fid)) % (2**32))
+        rng = np.random.default_rng(zlib.crc32(f"{vid}:{fid}".encode()))
         base = rng.standard_normal((num_patches, dim)).astype(np.float32) * 0.02
         objs = self.segments[vid]
         side = max(1, int(np.sqrt(num_patches)))
@@ -188,7 +191,7 @@ class SyntheticWorld:
             p = o.pos(fid)
             cell = min(num_patches - 1,
                        int(p[1] * side) * side + int(p[0] * side))
-            orng = np.random.default_rng(
-                hash((o.category, o.color, o.accessory)) % (2**32))
+            orng = np.random.default_rng(zlib.crc32(
+                f"{o.category}:{o.color}:{o.accessory}".encode()))
             base[cell] += orng.standard_normal(dim).astype(np.float32) * 0.2
         return base

@@ -46,6 +46,7 @@ from repro.core.stores import (SegmentStats, StoreSegment, append_stores,
                                demote_cold_segments, entity_segment_tiers,
                                seal_stores)
 from repro.core.streaming import _remap_pruned_ranges
+from repro.kernels import ops as kops
 from repro.kernels.ref import naive_topk
 from repro.kernels.topk_similarity_i4 import (dequantize_rows_i4,
                                               pack_nibbles, quantize_rows_i4,
@@ -478,6 +479,15 @@ def test_topk_i4_k_beyond_pad_falls_back_exact():
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
+def test_kernel_entries_refuse_k_beyond_pad():
+    """A direct kernel call cannot hold more than K_PAD results per query;
+    the engine's search never makes one (see the engine test below)."""
+    db = _normal(jax.random.PRNGKey(5), (300, 16))
+    valid = jnp.ones((300,), bool)
+    with pytest.raises(ValueError, match="at most 128"):
+        kops.topk_similarity_i4(db[:2], quantize_rows_i4(db), db, valid, 200)
+
+
 # ---------------------------------------------------------------------------
 # cold tier at the engine level
 # ---------------------------------------------------------------------------
@@ -526,6 +536,31 @@ def test_demotion_through_stores_setter(world, frag):
     r_hot = engine.query(q)
     engine.stores = demote_cold_segments(seg, demote_after=0)
     _assert_same(engine.query(q), r_hot)
+
+
+@pytest.mark.parametrize("mode", ["fp32", "int8"])
+def test_top_k_beyond_kernel_width_over_cold_segments(world, mode):
+    """top_k > 128 over a store with demoted segments: the range wider than
+    the kernels scans fp32, the cold ranges their int4 banks, EXPLAIN names
+    the fp32 range, and the answer equals the monolithic fp32 one. The
+    SQL text lists candidates in tie order, which the mixed-tier test
+    above pins (ROADMAP B1), so it is not compared here."""
+    caps = dict(entity_capacity=256, rel_capacity=4096)
+    mono = ingest(world, _emb(), **caps)
+    seg = ingest(world, _emb(), segment_range=(0, 2), **caps)
+    for s in range(2, SEGMENTS):
+        seg = ingest_incremental(seg, world, _emb(), (s, s + 1))
+    cold = demote_cold_segments(seg, demote_after=0)
+    assert "cold" in entity_segment_tiers(cold)
+    q = dataclasses.replace(_query(world), top_k=200)
+    sess = open_video_store(cold, _emb(), search_mode=mode)
+    assert "1 range(s) scan fp32 in jnp" in sess.explain(q).tree
+    assert sess.engine.plan_for(q).entity_match.k == 200
+    ref = LazyVLMEngine(mono, _emb()).query(q)
+    assert ref.segments
+    got = sess.query(q)
+    assert got.segments == ref.segments and got.scores == ref.scores
+    assert (got.end_frames == ref.end_frames).all()
 
 
 def test_placed_cold_tier_exact(world, frag, multi_device):
