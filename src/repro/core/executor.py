@@ -28,7 +28,6 @@ Host Python only orchestrates; device→host transfers all route through the
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -36,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.fault import (FaultGuard, FaultPolicy, FaultTolerantEmbedder,
                               FaultTolerantVerifier, ServiceUnavailable)
 from repro.core.physical import compile_physical
@@ -68,8 +68,12 @@ def _to_host(x) -> np.ndarray:
     round-trip a full-capacity ``(ΣT, cap)`` row mask — only the ``(ΣT,)``
     per-triple row counts (a fused device reduction) and the small
     candidate arrays come back to host.
+
+    Each call is a ``lazyvlm.sync`` span carrying the bytes moved: the host
+    waiting on the device.
     """
-    return np.asarray(x)
+    with obs.span("sync", bytes=getattr(x, "nbytes", 0)):
+        return np.asarray(x)
 
 
 def _is_append_descendant(old: VideoStores, new: VideoStores) -> bool:
@@ -141,6 +145,11 @@ class QueryStats:
     verify_rounds: int = 0      # cascade rounds (0 = single full pass)
     vlm_calls: int = 0
     frames_scanned_equivalent: int = 0   # what an e2e VLM would have ingested
+    # wall time per stage, from the stage's ``obs.span``. The batch path's
+    # stages each end in a ``_to_host`` sync, so they hold their device
+    # work; the single-query path's per-operator buckets time dispatch
+    # only (no sync closes an operator: device work lands on whichever
+    # later operator syncs)
     stage_seconds: Dict[str, float] = field(default_factory=dict)
     # -- graceful degradation (verifier ServiceUnavailable mid-query) -------
     degraded: bool = False               # some candidates went unverified
@@ -606,17 +615,21 @@ class LazyVLMEngine:
         ``_analyze`` (EXPLAIN ANALYZE, see ``Session.explain``) collects
         per-operator actual row counts into the given dict — analyze mode
         may issue extra small reductions the hot path skips.
+
+        Each operator's ``run`` is a ``lazyvlm.op.<stage>`` span, summed
+        into ``stats.stage_seconds[<stage>]``. That is dispatch time, not
+        device work: no sync closes an operator, so the device's time
+        lands on whichever later operator syncs.
         """
         st = self.stores
         pipe = self.physical_for(plan)
         ctx = ExecContext(engine=self, plan=plan, pipeline=pipe,
                           stats=QueryStats(), analyze=_analyze is not None)
         for op in pipe.ops:
-            t0 = time.perf_counter()
-            op.run(ctx)
+            with obs.span(f"op.{op.stage}") as s:
+                op.run(ctx)
             ctx.stats.stage_seconds[op.stage] = (
-                ctx.stats.stage_seconds.get(op.stage, 0.0)
-                + time.perf_counter() - t0)
+                ctx.stats.stage_seconds.get(op.stage, 0.0) + s.seconds)
         scores_np, segs_np, reach = ctx.vals["ranked"]
         keep = scores_np > 0
         ctx.stats.frames_scanned_equivalent = (st.num_segments
@@ -714,8 +727,11 @@ class LazyVLMEngine:
     def query_batch(self, queries: List[VMRQuery]) -> List[QueryResult]:
         """Compile every query (through the plan cache) and execute the
         batch; see :meth:`execute_batch` for the fusion/equivalence
-        contract."""
-        return self.execute_batch([self.plan_for(q) for q in queries])
+        contract. The call is one ``lazyvlm.engine.batch`` span."""
+        with obs.span("engine.batch"):
+            with obs.span("engine.plan"):
+                plans = [self.plan_for(q) for q in queries]
+            return self.execute_batch(plans)
 
     def execute_batch(self, plans: List[Plan]) -> List[QueryResult]:
         """Execute many compiled plans with fused, amortized stage launches.
@@ -740,233 +756,242 @@ class LazyVLMEngine:
         by the whole batch, and ``stats.stage_seconds`` holds the batch's
         stage wall-times (summing them across a batch's results overcounts
         by the batch size).
+
+        Each stage is a ``lazyvlm.engine.<stage>`` span: ``plan`` (the
+        physical pipelines), ``search`` (``entity_match``), ``select``
+        (``symbolic``), ``verify`` (``refine``), ``temporal`` and
+        ``results``; ``stage_seconds`` takes the keys in brackets from
+        their spans. Search, select and temporal each end in a
+        ``_to_host`` sync, so their times hold the device work they
+        launched.
         """
         if not plans:
             return []
         st = self.stores
         rel = st.relationships.table
         stats = [QueryStats() for _ in plans]
-        pipes = [self.physical_for(p) for p in plans]
-        t0 = time.perf_counter()
+        with obs.span("engine.plan"):
+            pipes = [self.physical_for(p) for p in plans]
 
         # -- stage 1: batched entity + predicate matching ---------------------
-        ent_cands = self._match_entities_batch(plans, stats)
-        pred_cands = self._match_predicates_batch(plans)
-        t_entity = time.perf_counter() - t0
+        with obs.span("engine.search") as search:
+            ent_cands = self._match_entities_batch(plans, stats)
+            pred_cands = self._match_predicates_batch(plans)
 
         # -- stage 2+3a: every query's triples in ONE fused selection ---------
-        t0 = time.perf_counter()
-        counts = [len(p.triple_select.triples) for p in plans]
-        row_offs = np.cumsum([0] + counts)
-        total = int(row_offs[-1])
-        t_pad = pow2_bucket(total)
-        width = pow2_bucket(max(v.shape[1] for v, _, _ in ent_cands),
-                            minimum=8)
-        m_width = pow2_bucket(max(ids.shape[1] for ids, _, _ in pred_cands),
-                              minimum=2)
-        sv = np.zeros((t_pad, width), np.int32)
-        se = np.zeros((t_pad, width), np.int32)
-        ov = np.zeros((t_pad, width), np.int32)
-        oe = np.zeros((t_pad, width), np.int32)
-        so = np.zeros((t_pad, width), bool)
-        oo = np.zeros((t_pad, width), bool)
-        pi = np.zeros((t_pad, m_width), np.int32)
-        po = np.zeros((t_pad, m_width), bool)
-        for qi, p in enumerate(plans):
-            vids, eids, eok = ent_cands[qi]
-            pids, pok, _ = pred_cands[qi]
-            ts = p.triple_select
-            w, m = vids.shape[1], pids.shape[1]
-            for pos, orig in enumerate(pipes[qi].order):
-                row = row_offs[qi] + pos
-                s_i, o_i = ts.subj_row[orig], ts.obj_row[orig]
-                p_i = ts.pred_row[orig]
-                sv[row, :w], se[row, :w] = vids[s_i], eids[s_i]
-                so[row, :w] = eok[s_i]
-                ov[row, :w], oe[row, :w] = vids[o_i], eids[o_i]
-                oo[row, :w] = eok[o_i]
-                pi[row, :m] = pids[p_i]
-                po[row, :m] = pok[p_i]
-        masks = _triple_selections(
-            rel["vid"], rel["fid"], rel["sid"], rel["rl"], rel["oid"],
-            rel.valid,
-            jnp.asarray(sv), jnp.asarray(se), jnp.asarray(so),
-            jnp.asarray(ov), jnp.asarray(oe), jnp.asarray(oo),
-            jnp.asarray(pi), jnp.asarray(po))               # (ΣT_pad, cap)
-        # symbolic-stage bookkeeping stays device-resident: per-triple row
-        # counts come back as ONE fused (ΣT_pad,) reduction, and SQL text is
-        # a lazy closure over the (already-host) candidate arrays — the
-        # full-capacity (ΣT, cap) mask is only materialized on host further
-        # down, if (and only if) a verifier needs row identities
-        row_counts = _to_host(masks.sum(axis=1))            # (ΣT_pad,)
-        renderers: List[Callable[[], List[str]]] = []
-        for qi, p in enumerate(plans):
-            lo = row_offs[qi]
-            pos_of = pipes[qi].pos_of
-            stats[qi].sql_rows_per_triple = [
-                int(row_counts[lo + pos_of[j]]) for j in range(counts[qi])]
-            renderers.append(make_sql_renderer(
-                [lo + pos_of[j] for j in range(counts[qi])],
-                sv, se, so, ov, oe, oo, pi, po, st.predicates.labels))
-        if self.adapt is not None:
-            # feed every query's estimated-vs-actual rows into the memo —
-            # the batch keeps its one fused launch (no mid-batch probing;
-            # the next compile of a drifted plan picks up the corrections)
-            from repro.core.physical.adapt import observe_filters
+        with obs.span("engine.select") as select:
+            counts = [len(p.triple_select.triples) for p in plans]
+            row_offs = np.cumsum([0] + counts)
+            total = int(row_offs[-1])
+            t_pad = pow2_bucket(total)
+            width = pow2_bucket(max(v.shape[1] for v, _, _ in ent_cands),
+                                minimum=8)
+            m_width = pow2_bucket(
+                max(ids.shape[1] for ids, _, _ in pred_cands), minimum=2)
+            sv = np.zeros((t_pad, width), np.int32)
+            se = np.zeros((t_pad, width), np.int32)
+            ov = np.zeros((t_pad, width), np.int32)
+            oe = np.zeros((t_pad, width), np.int32)
+            so = np.zeros((t_pad, width), bool)
+            oo = np.zeros((t_pad, width), bool)
+            pi = np.zeros((t_pad, m_width), np.int32)
+            po = np.zeros((t_pad, m_width), bool)
             for qi, p in enumerate(plans):
-                observe_filters(self.adapt, p, pipes[qi], row_counts,
-                                pipes[qi].store_version,
-                                offset=int(row_offs[qi]))
-        t_symbolic = time.perf_counter() - t0
+                vids, eids, eok = ent_cands[qi]
+                pids, pok, _ = pred_cands[qi]
+                ts = p.triple_select
+                w, m = vids.shape[1], pids.shape[1]
+                for pos, orig in enumerate(pipes[qi].order):
+                    row = row_offs[qi] + pos
+                    s_i, o_i = ts.subj_row[orig], ts.obj_row[orig]
+                    p_i = ts.pred_row[orig]
+                    sv[row, :w], se[row, :w] = vids[s_i], eids[s_i]
+                    so[row, :w] = eok[s_i]
+                    ov[row, :w], oe[row, :w] = vids[o_i], eids[o_i]
+                    oo[row, :w] = eok[o_i]
+                    pi[row, :m] = pids[p_i]
+                    po[row, :m] = pok[p_i]
+            masks = _triple_selections(
+                rel["vid"], rel["fid"], rel["sid"], rel["rl"], rel["oid"],
+                rel.valid,
+                jnp.asarray(sv), jnp.asarray(se), jnp.asarray(so),
+                jnp.asarray(ov), jnp.asarray(oe), jnp.asarray(oo),
+                jnp.asarray(pi), jnp.asarray(po))           # (ΣT_pad, cap)
+            # symbolic-stage bookkeeping stays device-resident: per-triple
+            # row counts come back as ONE fused (ΣT_pad,) reduction, and SQL
+            # text is a lazy closure over the (already-host) candidate arrays
+            # — the full-capacity (ΣT, cap) mask is only materialized on host
+            # further down, if (and only if) a verifier needs row identities
+            row_counts = _to_host(masks.sum(axis=1))            # (ΣT_pad,)
+            renderers: List[Callable[[], List[str]]] = []
+            for qi, p in enumerate(plans):
+                lo = row_offs[qi]
+                pos_of = pipes[qi].pos_of
+                stats[qi].sql_rows_per_triple = [
+                    int(row_counts[lo + pos_of[j]]) for j in range(counts[qi])]
+                renderers.append(make_sql_renderer(
+                    [lo + pos_of[j] for j in range(counts[qi])],
+                    sv, se, so, ov, oe, oo, pi, po, st.predicates.labels))
+            if self.adapt is not None:
+                # feed every query's estimated-vs-actual rows into the memo —
+                # the batch keeps its one fused launch (no mid-batch probing;
+                # the next compile of a drifted plan picks up the corrections)
+                from repro.core.physical.adapt import observe_filters
+                for qi, p in enumerate(plans):
+                    observe_filters(self.adapt, p, pipes[qi], row_counts,
+                                    pipes[qi].store_version,
+                                    offset=int(row_offs[qi]))
 
         # -- stage 3b: ONE deduped VLM pass across the whole batch ------------
-        # rows of plans compiled with verify disabled are excluded from the
-        # candidate set and keep their symbolic masks; budgeted plans run
-        # the cascade on their own slice (seeded with the fused pass's
-        # verdict memo), so execution matches each plan's advertised
-        # VlmVerify node even in a mixed batch
-        t0 = time.perf_counter()
-        verif = np.zeros((t_pad,), bool)
-        budgeted: List[int] = []
-        for qi, p in enumerate(plans):
-            if not p.verify.enabled:
-                continue
-            if p.verify.budget > 0:
-                budgeted.append(qi)
-            else:
-                verif[row_offs[qi]: row_offs[qi] + counts[qi]] = True
-        if self.verifier is not None and (verif.any() or budgeted):
-            # row identities are needed now: this is the ONE place the
-            # no-verifier fast path never reaches
-            masks_np = _to_host(masks)
-            memo: Dict[tuple, bool] = {}
-            cols = None
-            if verif.any():
-                try:
-                    out = self._verify_rows(rel, masks_np & verif[:, None])
-                except ServiceUnavailable as exc:
-                    # verifier gone during the fused pass: every full-verify
-                    # plan in the batch degrades (confirmed-only = nothing;
-                    # their candidates are excluded and attached unverified);
-                    # budgeted plans below still run — their cascades may
-                    # complete from memo-free certificates or degrade too
-                    out = None
+        with obs.span("engine.verify") as verify:
+            # rows of plans compiled with verify disabled are excluded from the
+            # candidate set and keep their symbolic masks; budgeted plans run
+            # the cascade on their own slice (seeded with the fused pass's
+            # verdict memo), so execution matches each plan's advertised
+            # VlmVerify node even in a mixed batch
+            verif = np.zeros((t_pad,), bool)
+            budgeted: List[int] = []
+            for qi, p in enumerate(plans):
+                if not p.verify.enabled:
+                    continue
+                if p.verify.budget > 0:
+                    budgeted.append(qi)
+                else:
+                    verif[row_offs[qi]: row_offs[qi] + counts[qi]] = True
+            if self.verifier is not None and (verif.any() or budgeted):
+                # row identities are needed now: this is the ONE place the
+                # no-verifier fast path never reaches
+                masks_np = _to_host(masks)
+                memo: Dict[tuple, bool] = {}
+                cols = None
+                if verif.any():
+                    try:
+                        out = self._verify_rows(rel, masks_np & verif[:, None])
+                    except ServiceUnavailable as exc:
+                        # verifier gone during the fused pass: every
+                        # full-verify plan in the batch degrades
+                        # (confirmed-only = nothing; their candidates are
+                        # excluded and attached unverified); budgeted plans
+                        # below still run — their cascades may complete from
+                        # memo-free certificates or degrade too
+                        out = None
+                        cols = {k: _to_host(rel[k]) for k in REL_SCHEMA}
+                        calls = getattr(self.verifier, "calls", 0)
+                        for qi, p in enumerate(plans):
+                            if not p.verify.enabled or p.verify.budget > 0:
+                                continue
+                            lo = row_offs[qi]
+                            q_any = masks_np[lo: lo + counts[qi]].any(axis=0)
+                            ridx = np.nonzero(q_any)[0]
+                            if len(ridx) == 0:
+                                continue    # no candidates of its own: exact
+                            stats[qi].vlm_calls = calls
+                            stats[qi].degraded = True
+                            stats[qi].degraded_cause = exc
+                            stats[qi].unverified_rows = np.unique(
+                                np.stack([cols[k][ridx] for k in REL_SCHEMA],
+                                         axis=1), axis=0)
+                            stats[qi].refine_candidates = len(
+                                stats[qi].unverified_rows)
+                        masks = masks & ~jnp.asarray(verif)[:, None]
+                    if out is not None:
+                        keep_rows, uniq, verdict_u, cols = out
+                        for u, vd in zip(uniq, verdict_u):
+                            memo[tuple(int(x) for x in u)] = bool(vd)
+                        calls = getattr(self.verifier, "calls", 0)
+                        for qi, p in enumerate(plans):
+                            if not p.verify.enabled or p.verify.budget > 0:
+                                continue
+                            lo = row_offs[qi]
+                            q_any = masks_np[lo: lo + counts[qi]].any(axis=0)
+                            ridx = np.nonzero(q_any)[0]
+                            stats[qi].vlm_calls = calls
+                            if len(ridx) == 0:
+                                continue
+                            qrows = np.stack(
+                                [cols[k][ridx] for k in REL_SCHEMA], axis=1)
+                            stats[qi].refine_candidates = len(
+                                np.unique(qrows, axis=0))
+                            stats[qi].refine_passed = len(
+                                np.unique(qrows[keep_rows[ridx]], axis=0))
+                            stats[qi].refine_verified = (
+                                stats[qi].refine_candidates)
+                        masks = masks & (jnp.asarray(keep_rows)[None, :]
+                                         | ~jnp.asarray(verif)[:, None])
+                if cols is None and budgeted:
                     cols = {k: _to_host(rel[k]) for k in REL_SCHEMA}
-                    calls = getattr(self.verifier, "calls", 0)
-                    for qi, p in enumerate(plans):
-                        if not p.verify.enabled or p.verify.budget > 0:
-                            continue
-                        lo = row_offs[qi]
-                        q_any = masks_np[lo: lo + counts[qi]].any(axis=0)
-                        ridx = np.nonzero(q_any)[0]
-                        if len(ridx) == 0:
-                            continue    # no candidates of its own: exact
-                        stats[qi].vlm_calls = calls
-                        stats[qi].degraded = True
-                        stats[qi].degraded_cause = exc
-                        stats[qi].unverified_rows = np.unique(
-                            np.stack([cols[k][ridx] for k in REL_SCHEMA],
-                                     axis=1), axis=0)
-                        stats[qi].refine_candidates = len(
-                            stats[qi].unverified_rows)
-                    masks = masks & ~jnp.asarray(verif)[:, None]
-                if out is not None:
-                    keep_rows, uniq, verdict_u, cols = out
-                    for u, vd in zip(uniq, verdict_u):
-                        memo[tuple(int(x) for x in u)] = bool(vd)
-                    calls = getattr(self.verifier, "calls", 0)
-                    for qi, p in enumerate(plans):
-                        if not p.verify.enabled or p.verify.budget > 0:
-                            continue
-                        lo = row_offs[qi]
-                        q_any = masks_np[lo: lo + counts[qi]].any(axis=0)
-                        ridx = np.nonzero(q_any)[0]
-                        stats[qi].vlm_calls = calls
-                        if len(ridx) == 0:
-                            continue
-                        qrows = np.stack([cols[k][ridx] for k in REL_SCHEMA],
-                                         axis=1)
-                        stats[qi].refine_candidates = len(
-                            np.unique(qrows, axis=0))
-                        stats[qi].refine_passed = len(
-                            np.unique(qrows[keep_rows[ridx]], axis=0))
-                        stats[qi].refine_verified = (
-                            stats[qi].refine_candidates)
-                    masks = masks & (jnp.asarray(keep_rows)[None, :]
-                                     | ~jnp.asarray(verif)[:, None])
-            if cols is None and budgeted:
-                cols = {k: _to_host(rel[k]) for k in REL_SCHEMA}
-            for qi in budgeted:
-                p, pipe = plans[qi], pipes[qi]
-                lo, hi = row_offs[qi], row_offs[qi] + counts[qi]
-                ids_q, ok_q, vals_q = pred_cands[qi]
-                keep_q = cascade_for_plan(
-                    engine=self, plan=p, pipeline=pipe,
-                    masks=masks[lo:hi], masks_np=masks_np[lo:hi],
-                    pred_scores=(vals_q, ids_q, ok_q), stats=stats[qi],
-                    memo=memo, cols=cols)
-                if keep_q is not None:
-                    sel = np.zeros((t_pad,), bool)
-                    sel[lo:hi] = True
-                    masks = masks & (jnp.asarray(keep_q)[None, :]
-                                     | ~jnp.asarray(sel)[:, None])
-        t_refine = time.perf_counter() - t0
+                for qi in budgeted:
+                    p, pipe = plans[qi], pipes[qi]
+                    lo, hi = row_offs[qi], row_offs[qi] + counts[qi]
+                    ids_q, ok_q, vals_q = pred_cands[qi]
+                    keep_q = cascade_for_plan(
+                        engine=self, plan=p, pipeline=pipe,
+                        masks=masks[lo:hi], masks_np=masks_np[lo:hi],
+                        pred_scores=(vals_q, ids_q, ok_q), stats=stats[qi],
+                        memo=memo, cols=cols)
+                    if keep_q is not None:
+                        sel = np.zeros((t_pad,), bool)
+                        sel[lo:hi] = True
+                        masks = masks & (jnp.asarray(keep_q)[None, :]
+                                         | ~jnp.asarray(sel)[:, None])
 
         # -- stage 4: conjunction + signature-grouped temporal DP -------------
-        t0 = time.perf_counter()
-        bitmaps = _masks_to_bitmaps(rel["vid"], rel["fid"], masks,
-                                    st.num_segments, st.frames_per_segment)
-        # frame-spec conjunction: one gather + AND-reduce over every
-        # (query, frame) pair; pad slots act as identity (all-True), matching
-        # the single path's ones-initialized accumulator
-        fcounts = [len(p.conjoin.frames) for p in plans]
-        frame_offs = np.cumsum([0] + fcounts)
-        n_qf = int(frame_offs[-1])
-        max_tr = pow2_bucket(
-            max((len(f) for p in plans for f in p.conjoin.frames),
-                default=1) or 1, minimum=2)
-        qf_pad = pow2_bucket(n_qf)
-        idx_mat = np.zeros((qf_pad, max_tr), np.int32)
-        pad_mat = np.ones((qf_pad, max_tr), bool)
-        for qi, p in enumerate(plans):
-            pos_of = pipes[qi].pos_of
-            for fj, fr in enumerate(p.conjoin.frames):
-                r = frame_offs[qi] + fj
-                for c, ti in enumerate(fr):
-                    idx_mat[r, c] = row_offs[qi] + pos_of[ti]
-                    pad_mat[r, c] = False
-        fmaps = _conjoin_bitmaps(bitmaps, jnp.asarray(idx_mat),
-                                 jnp.asarray(pad_mat))      # (qf_pad, V, F)
-        frame_maps_all = [
-            [fmaps[frame_offs[qi] + j] for j in range(fcounts[qi])]
-            for qi in range(len(plans))]
-        matched = temporal_lib.temporal_match_batch_sigs(
-            frame_maps_all, [p.chain_signature() for p in plans])
-        ends_stack = jnp.stack([ends for _, ends in matched])  # (B, V, F)
-        kmax = max(p.temporal.top_k for p in plans)   # segment-clamped
-        scores_b, seg_b = temporal_lib.rank_segments_batch(ends_stack, kmax)
-        scores_np, seg_np = _to_host(scores_b), _to_host(seg_b)
-        t_temporal = time.perf_counter() - t0
+        with obs.span("engine.temporal") as chain:
+            bitmaps = _masks_to_bitmaps(rel["vid"], rel["fid"], masks,
+                                        st.num_segments, st.frames_per_segment)
+            # frame-spec conjunction: one gather + AND-reduce over every
+            # (query, frame) pair; pad slots act as identity (all-True),
+            # matching the single path's ones-initialized accumulator
+            fcounts = [len(p.conjoin.frames) for p in plans]
+            frame_offs = np.cumsum([0] + fcounts)
+            n_qf = int(frame_offs[-1])
+            max_tr = pow2_bucket(
+                max((len(f) for p in plans for f in p.conjoin.frames),
+                    default=1) or 1, minimum=2)
+            qf_pad = pow2_bucket(n_qf)
+            idx_mat = np.zeros((qf_pad, max_tr), np.int32)
+            pad_mat = np.ones((qf_pad, max_tr), bool)
+            for qi, p in enumerate(plans):
+                pos_of = pipes[qi].pos_of
+                for fj, fr in enumerate(p.conjoin.frames):
+                    r = frame_offs[qi] + fj
+                    for c, ti in enumerate(fr):
+                        idx_mat[r, c] = row_offs[qi] + pos_of[ti]
+                        pad_mat[r, c] = False
+            fmaps = _conjoin_bitmaps(bitmaps, jnp.asarray(idx_mat),
+                                     jnp.asarray(pad_mat))  # (qf_pad, V, F)
+            frame_maps_all = [
+                [fmaps[frame_offs[qi] + j] for j in range(fcounts[qi])]
+                for qi in range(len(plans))]
+            matched = temporal_lib.temporal_match_batch_sigs(
+                frame_maps_all, [p.chain_signature() for p in plans])
+            ends_stack = jnp.stack([ends for _, ends in matched])  # (B, V, F)
+            kmax = max(p.temporal.top_k for p in plans)   # segment-clamped
+            scores_b, seg_b = temporal_lib.rank_segments_batch(ends_stack,
+                                                               kmax)
+            scores_np, seg_np = _to_host(scores_b), _to_host(seg_b)
 
-        results = []
-        for qi, p in enumerate(plans):
-            s_q, g_q = topk_prefix(scores_np[qi], seg_np[qi],
-                                   p.temporal.top_k)
-            keep = s_q > 0
-            stats[qi].frames_scanned_equivalent = (st.num_segments
-                                                   * st.frames_per_segment)
-            stats[qi].stage_seconds = {
-                "entity_match": t_entity, "symbolic": t_symbolic,
-                "refine": t_refine, "temporal": t_temporal}
-            results.append(QueryResult(
-                segments=[int(v) for v in g_q[keep]],
-                scores=[int(x) for x in s_q[keep]],
-                end_frames=_to_host(matched[qi][1]),
-                sql_renderer=renderers[qi],
-                stats=stats[qi],
-                degraded=stats[qi].degraded,
-                unverified=stats[qi].unverified_rows,
-            ))
+        seconds = {"entity_match": search.seconds,
+                   "symbolic": select.seconds,
+                   "refine": verify.seconds, "temporal": chain.seconds}
+        with obs.span("engine.results"):
+            results = []
+            for qi, p in enumerate(plans):
+                s_q, g_q = topk_prefix(scores_np[qi], seg_np[qi],
+                                       p.temporal.top_k)
+                keep = s_q > 0
+                stats[qi].frames_scanned_equivalent = (st.num_segments
+                                                       * st.frames_per_segment)
+                stats[qi].stage_seconds = dict(seconds)
+                results.append(QueryResult(
+                    segments=[int(v) for v in g_q[keep]],
+                    scores=[int(x) for x in s_q[keep]],
+                    end_frames=_to_host(matched[qi][1]),
+                    sql_renderer=renderers[qi],
+                    stats=stats[qi],
+                    degraded=stats[qi].degraded,
+                    unverified=stats[qi].unverified_rows,
+                ))
         return results
 
     # -- refinement helpers ------------------------------------------------------

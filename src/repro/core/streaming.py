@@ -44,13 +44,13 @@ segments, scores, ``end_frames``, SQL — is bitwise cold-run-identical.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import temporal as temporal_lib
 from repro.core.physical import stages
 from repro.core.plan import Plan, pow2_bucket
@@ -256,23 +256,24 @@ class Subscription:
         version = engine.store_version
         if self.result is not None and version == self._version:
             return self.result
-        t0 = time.perf_counter()
-        plan = engine.plan_for(self.query)
-        segs = engine.stores.segments or _bootstrap_segments(engine.stores)
-        # register the chain frontier with the placement-aware pass: the
-        # active segment and the most recently sealed one are where chain
-        # continuations land, so placed engines co-locate them — an
-        # incremental refresh then touches only the devices owning new
-        # segments (the delta scan reads appended rows only; sealed placed
-        # banks stay where they are)
-        engine.frontier_sids = tuple(s.sid for s in segs[-2:])
-        pipe = engine.physical_for(plan)
-        prev = self.result
-        result = self._evaluate(plan, pipe, segs)
-        self._version = version
-        self.result = result
-        self.stats.refreshes += 1
-        result.stats.stage_seconds["refresh"] = time.perf_counter() - t0
+        with obs.span("subscription.refresh") as span:
+            plan = engine.plan_for(self.query)
+            segs = (engine.stores.segments
+                    or _bootstrap_segments(engine.stores))
+            # register the chain frontier with the placement-aware pass:
+            # the active segment and the most recently sealed one are where
+            # chain continuations land, so placed engines co-locate them —
+            # an incremental refresh then touches only the devices owning
+            # new segments (the delta scan reads appended rows only; sealed
+            # placed banks stay where they are)
+            engine.frontier_sids = tuple(s.sid for s in segs[-2:])
+            pipe = engine.physical_for(plan)
+            prev = self.result
+            result = self._evaluate(plan, pipe, segs)
+            self._version = version
+            self.result = result
+            self.stats.refreshes += 1
+        result.stats.stage_seconds["refresh"] = span.seconds
         if self._listeners:
             delta = _result_delta(prev, result, store_version=version,
                                   refresh_index=self.stats.refreshes)

@@ -56,6 +56,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Union
 
+from repro import obs
 from repro.core.executor import LazyVLMEngine, QueryResult
 from repro.core.fault import (DeviceLossError, ServiceUnavailable,
                               TransientFault)
@@ -324,7 +325,14 @@ class ServingRuntime:
         pipeline through the plan cache, and applies backpressure: a full
         queue returns a :class:`SubmitRejection` — a structured value, not
         an exception from deep in the engine — and drops nothing silently.
+        The call is one ``lazyvlm.runtime.submit`` span.
         """
+        with obs.span("runtime.submit"):
+            return self._submit(query, session, priority, deadline_s)
+
+    def _submit(self, query: QueryLike, session: str, priority: int,
+                deadline_s: Optional[float]
+                ) -> Union[RuntimeTicket, SubmitRejection]:
         sess = self.registry.open(session)
         q = sess.resolve(query)
         q.validate()
@@ -573,15 +581,24 @@ class ServingRuntime:
         :class:`~repro.core.compact.CompactionPolicy` configured, an empty
         queue runs one budgeted compaction/demotion pass instead of
         returning immediately (see :meth:`run_maintenance`) — interactive
-        work always wins the tick."""
+        work always wins the tick.
+
+        The round is one ``lazyvlm.runtime.tick`` span; inside it,
+        ``lazyvlm.runtime.admit`` brackets the choice of the batch and
+        ``lazyvlm.runtime.execute`` the engine call."""
+        with obs.span("runtime.tick"):
+            return self._tick(now)
+
+    def _tick(self, now: Optional[float]) -> int:
         if not self._queue:
             n = self.run_maintenance(now)
             self._sync_adapt_metrics()
             return n
         if now is None:
             now = self.clock()
-        self._expire_deadlines(now)
-        batch = self._select_batch(now)
+        with obs.span("runtime.admit"):
+            self._expire_deadlines(now)
+            batch = self._select_batch(now)
         if not batch:          # everything eligible is inside a backoff gate
             return 0
         queries = [e for e in batch if e.kind == "query"]
@@ -684,7 +701,13 @@ class ServingRuntime:
             t.execute_started_at = started
             t.coalesced_with = len(tickets)
         try:
-            results = self.engine.query_batch([t.query for t in tickets])
+            # metadata ties the batch's spans to its tickets: the runtime's
+            # batch counter and the ticket ids, space-separated (the
+            # profiler's metadata encoding reserves ",")
+            with obs.span("runtime.execute", batch=self.metrics.batches,
+                          qids=" ".join(str(t.qid) for t in tickets)):
+                results = self.engine.query_batch(
+                    [t.query for t in tickets])
             error = None
         except Exception as exc:
             if self._handle_query_failure(entries, exc):
