@@ -123,12 +123,17 @@ def _triple_selections(rel_cols_vid, rel_cols_fid, rel_cols_sid, rel_cols_rl,
     subj_*/obj_*: (T, k) candidate (vid,eid) pairs per triple;
     pred_*: (T, m) candidate predicate labels per triple.
     Returns (T, cap) row masks. Rows are independent, so any row order
-    (e.g. the cost-based one) produces per-row bit-identical masks.
+    (e.g. the cost-based one) produces per-row bit-identical masks. The
+    rows' (vid, sid) and (vid, oid) packs are made once, outside the
+    per-triple ``vmap``.
     """
+    s_vals = sops.pack_pairs(rel_cols_vid, rel_cols_sid)
+    o_vals = sops.pack_pairs(rel_cols_vid, rel_cols_oid)
+
     def one(svid, seid, sok, ovid, oeid, ook, pid, pok):
         m = rel_valid
-        m &= sops.isin_pairs(rel_cols_vid, rel_cols_sid, svid, seid, sok)
-        m &= sops.isin_pairs(rel_cols_vid, rel_cols_oid, ovid, oeid, ook)
+        m &= sops.isin(s_vals, sops.pack_pairs(svid, seid), sok)
+        m &= sops.isin(o_vals, sops.pack_pairs(ovid, oeid), ook)
         m &= sops.isin(rel_cols_rl, pid, pok)
         return m
 
@@ -155,11 +160,15 @@ def _delta_triple_selections(rel_vid, rel_fid, rel_sid, rel_rl, rel_oid,
     l = jnp.asarray(lo, jnp.int32)
     sl = lambda col: jax.lax.dynamic_slice_in_dim(col, l, bucket)
     valid = sl(rel_valid) & (jnp.arange(bucket) < span)
+    vid, rl = sl(rel_vid), sl(rel_rl)
+    s_vals = sops.pack_pairs(vid, sl(rel_sid))
+    o_vals = sops.pack_pairs(vid, sl(rel_oid))
+
     def one(svid, seid, sok, ovid, oeid, ook, pid, pok):
         m = valid
-        m &= sops.isin_pairs(sl(rel_vid), sl(rel_sid), svid, seid, sok)
-        m &= sops.isin_pairs(sl(rel_vid), sl(rel_oid), ovid, oeid, ook)
-        m &= sops.isin(sl(rel_rl), pid, pok)
+        m &= sops.isin(s_vals, sops.pack_pairs(svid, seid), sok)
+        m &= sops.isin(o_vals, sops.pack_pairs(ovid, oeid), ook)
+        m &= sops.isin(rl, pid, pok)
         return m
 
     masks = jax.vmap(one)(subj_vid, subj_eid, subj_ok,

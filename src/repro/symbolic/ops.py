@@ -2,8 +2,9 @@
 
 TPU-native realizations:
   * ``filter_``     — predicate mask intersection (vectorized select).
-  * ``semi_join``   — ``col IN keys`` via sorted keys + searchsorted
-                      (the TPU analogue of a hash semi-join).
+  * ``semi_join``   — ``col IN keys`` by direct comparison of every row
+                      against the (small) key set, a block of keys at a
+                      time (``isin``); no sort, search or gather.
   * ``equi_join``   — sort-merge join with a declared output capacity and an
                       overflow flag (never silently drops).
   * ``distinct_pairs`` / ``scatter_bitmap`` — group rows into a dense
@@ -24,6 +25,10 @@ import jax.numpy as jnp
 from repro.symbolic.table import Table
 
 INVALID = jnp.int32(2**31 - 1)
+
+# ``isin`` compares rows against this many keys per loop step. The key axis
+# is padded to a multiple of it with ``INVALID``, which never matches.
+KEY_BLOCK = 16
 
 # ``isin_pairs`` packs (first, second) id pairs into one int32 as
 # first * PAIR_RADIX + second. Both components must stay inside these
@@ -51,12 +56,28 @@ def isin(values: jax.Array, keys: jax.Array, keys_valid: jax.Array
          ) -> jax.Array:
     """Vector membership: values[i] ∈ {keys[j] : keys_valid[j]}.
 
-    Sorted-keys + searchsorted: O((n+k) log k), static shapes.
+    Blocked direct comparison, O(n·k) compares and O(n) working memory:
+    a ``fori_loop`` over blocks of ``KEY_BLOCK`` keys ORs
+    ``values == key`` into one (n,) accumulator (``(T, n)`` under
+    ``vmap``). Invalid and padding keys become ``INVALID``, and a value
+    equal to ``INVALID`` never matches, so neither can. Duplicate keys
+    are harmless. Direct comparison keeps the TPU off per-row dynamic
+    gathers (a binary search over sorted keys gathers log k times a row);
+    the loop keeps XLA from materialising the (k, n) comparison.
     """
-    skeys = jnp.sort(jnp.where(keys_valid, keys, INVALID))
-    idx = jnp.searchsorted(skeys, values)
-    idx = jnp.minimum(idx, skeys.shape[0] - 1)
-    return (skeys[idx] == values) & (values != INVALID)
+    keys = jnp.where(keys_valid, keys, INVALID)
+    keys = jnp.pad(keys, (0, -keys.shape[0] % KEY_BLOCK),
+                   constant_values=INVALID)
+
+    def block(b, acc):
+        blk = jax.lax.dynamic_slice_in_dim(keys, b * KEY_BLOCK, KEY_BLOCK)
+        for j in range(KEY_BLOCK):
+            acc = acc | (values == blk[j])
+        return acc
+
+    hit = jax.lax.fori_loop(0, keys.shape[0] // KEY_BLOCK, block,
+                            jnp.zeros(values.shape, bool))
+    return hit & (values != INVALID)
 
 
 def semi_join(t: Table, col: str, keys: jax.Array, keys_valid: jax.Array
@@ -65,20 +86,24 @@ def semi_join(t: Table, col: str, keys: jax.Array, keys_valid: jax.Array
     return filter_(t, isin(_masked_col(t, col), keys, keys_valid))
 
 
+def pack_pairs(first: jax.Array, second: jax.Array,
+               radix: int = PAIR_RADIX) -> jax.Array:
+    """Radix-pack id pairs into one int32: ``first * radix + second``."""
+    return first * radix + second
+
+
 def isin_pairs(a1: jax.Array, a2: jax.Array, k1: jax.Array, k2: jax.Array,
                keys_valid: jax.Array, radix: int = PAIR_RADIX) -> jax.Array:
     """Membership of pairs (a1, a2) in the masked key-pair set (k1, k2).
 
-    Pairs are radix-packed into int32 (JAX default has x64 disabled), so both
-    second components must be < ``radix`` and first components < 2^31/radix.
+    Both sides are radix-packed (:func:`pack_pairs`) and tested by
+    :func:`isin`. Pairs are packed into int32 (JAX default has x64
+    disabled), so second components must be < ``radix`` and first
+    components < 2^31/radix. Callers that test one row set against many
+    key sets pack the rows once and call :func:`isin` directly.
     """
-    pack = lambda x, y: x * radix + y
-    vals = pack(a1, a2)
-    keys = pack(k1, k2)
-    big = jnp.int32(2**31 - 1)
-    skeys = jnp.sort(jnp.where(keys_valid, keys, big))
-    idx = jnp.minimum(jnp.searchsorted(skeys, vals), skeys.shape[0] - 1)
-    return (skeys[idx] == vals) & (vals != big)
+    return isin(pack_pairs(a1, a2, radix), pack_pairs(k1, k2, radix),
+                keys_valid)
 
 
 def sort_by(t: Table, col: str) -> Table:

@@ -1,6 +1,8 @@
 """Physical execution layer: store statistics, per-operator cost estimates,
 the cost-based triple ordering pass (and its result-invariance property),
 pipeline rendering, and the scheduler's cost currency."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -8,6 +10,7 @@ from repro.core import LazyVLMEngine, compile_plan, example_2_1
 from repro.core.physical import StoreStats, compile_physical
 from repro.core.physical.compile import order_triple_filters
 from repro.core.physical.cost import estimate_triple_rows
+from repro.core.physical import stages
 from repro.core.physical.ops import TripleFilterOp, VlmVerifyOp
 from repro.core.query import (Entity, FrameSpec, Relationship, Triple,
                               VMRQuery)
@@ -255,3 +258,71 @@ def test_estimate_cost_scales_with_query_size(stores):
 
 def _descs_from(stores):
     return sorted(set(stores.entity_desc.values()))
+
+
+def _selection_case(cap=4096, n_valid=3000, t=8, t_real=6, k=24, m=3):
+    """A relationship table with spare capacity (the spare rows repeat
+    valid rows' ids, so only the validity mask keeps them out) and ``t``
+    triples' candidates, the last ``t - t_real`` of them padding with
+    all-False candidates, as the batched path pads to a power of two."""
+    rng = np.random.default_rng(11)
+    cols = {c: rng.integers(0, hi, cap).astype(np.int32) for c, hi in
+            (("vid", 12), ("fid", 16), ("sid", 6), ("rl", 5), ("oid", 6))}
+    for c in cols.values():
+        c[n_valid:] = c[: cap - n_valid]
+    valid = np.arange(cap) < n_valid
+    pick = lambda hi, shape: rng.integers(0, hi, shape).astype(np.int32)
+    cands = [pick(12, (t, k)), pick(6, (t, k)), rng.random((t, k)) < 0.6,
+             pick(12, (t, k)), pick(6, (t, k)), rng.random((t, k)) < 0.6,
+             pick(5, (t, m)), rng.random((t, m)) < 0.7]
+    cands[-1][:, 0] = True          # the engine always keeps the best label
+    for c in cands:
+        c[t_real:] = 0
+    return cols, valid, cands
+
+
+def _selection_reference(cols, valid, cands):
+    sv, se, so, ov, oe, oo, pi, po = cands
+    pack = lambda a, b: a.astype(np.int64) * (1 << 15) + b
+    rows_s, rows_o = pack(cols["vid"], cols["sid"]), pack(cols["vid"],
+                                                          cols["oid"])
+    return np.stack([
+        valid
+        & np.isin(rows_s, pack(sv[i], se[i])[so[i]])
+        & np.isin(rows_o, pack(ov[i], oe[i])[oo[i]])
+        & np.isin(cols["rl"], pi[i][po[i]])
+        for i in range(sv.shape[0])])
+
+
+def test_triple_selections_match_numpy_reference():
+    cols, valid, cands = _selection_case()
+    want = _selection_reference(cols, valid, cands)
+    rel = [jnp.asarray(cols[c]) for c in ("vid", "fid", "sid", "rl", "oid")]
+    args = [jnp.asarray(c) for c in cands]
+    got = np.asarray(stages._triple_selections(*rel, jnp.asarray(valid),
+                                               *args))
+    assert got.dtype == bool and got.shape == want.shape
+    assert (got == want).all()
+    assert want[:6].any(axis=1).all() and not got[6:].any()
+    # the incremental path's window: the same columns, nothing past span
+    lo, span, bucket = 1024, 1500, 2048
+    dmask, counts = stages._delta_triple_selections(
+        *rel, jnp.asarray(valid), lo, span, bucket, *args)
+    dwant = np.zeros((want.shape[0], bucket), bool)
+    dwant[:, :span] = want[:, lo:lo + span]
+    assert (np.asarray(dmask) == dwant).all()
+    assert (np.asarray(counts) == dwant.sum(axis=1)).all()
+
+
+def test_triple_selections_working_memory_stays_small():
+    """The membership loop keeps a (T, N) accumulator: compiled at a
+    batch's largest shape it needs well under the broadcast compare's
+    (T, k, N) bytes (17.4 GB here)."""
+    t, k, m, n = 32, 128, 2, 1 << 21
+    s = jax.ShapeDtypeStruct
+    args = ([s((n,), jnp.int32)] * 5 + [s((n,), jnp.bool_)]
+            + [s((t, k), jnp.int32), s((t, k), jnp.int32),
+               s((t, k), jnp.bool_)] * 2
+            + [s((t, m), jnp.int32), s((t, m), jnp.bool_)])
+    compiled = stages._triple_selections.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

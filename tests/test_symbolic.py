@@ -1,6 +1,7 @@
 """Property-based tests: the TPU relational engine vs a Python oracle."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hyp import given, settings, st
 
 from repro.symbolic import ops as sops
@@ -118,3 +119,50 @@ def test_group_count(rows):
     for g, _ in rows:
         want[g] += 1
     assert (counts == want).all()
+
+
+SENTINEL = 2**31 - 1
+
+
+def _membership_case(width: int):
+    """Rows and keys for one key width: every key is some row's pair; a
+    third of the keys are invalid (so they equal row values but must not
+    match), the last key duplicates the first, a valid key packs to the
+    sentinel, and so does the last row."""
+    rng = np.random.default_rng(width)
+    n = 300
+    a1 = rng.integers(0, 8, n).astype(np.int32)
+    a2 = rng.integers(0, 16, n).astype(np.int32)
+    a1[-1], a2[-1] = sops.PAIR_FIRST_LIMIT - 1, sops.PAIR_RADIX - 1
+    pick = rng.integers(0, n - 1, width)
+    k1, k2 = a1[pick].copy(), a2[pick].copy()
+    kv = np.arange(width) % 3 != 1
+    if width >= 2:
+        k1[-1], k2[-1], kv[-1] = k1[0], k2[0], kv[0]
+    if width >= 3:
+        k1[2], k2[2], kv[2] = a1[-1], a2[-1], True
+    return a1, a2, k1, k2, kv
+
+
+def _set_reference(vals, keys, kv):
+    keyset = {int(k) for k, ok in zip(keys, kv) if ok}
+    return np.array([int(v) in keyset and int(v) != SENTINEL for v in vals])
+
+
+@pytest.mark.parametrize("width", [1, 3, 8, 16, 17, 128, 1000])
+def test_isin_matches_set_reference(width):
+    a1, a2, k1, k2, kv = _membership_case(width)
+    vals = a1.astype(np.int64) * sops.PAIR_RADIX + a2
+    keys = k1.astype(np.int64) * sops.PAIR_RADIX + k2
+    assert vals[-1] == SENTINEL
+    for valid in (kv, np.zeros(width, bool)):
+        want = _set_reference(vals, keys, valid)
+        got_pairs = sops.isin_pairs(jnp.asarray(a1), jnp.asarray(a2),
+                                    jnp.asarray(k1), jnp.asarray(k2),
+                                    jnp.asarray(valid))
+        got = sops.isin(jnp.asarray(vals.astype(np.int32)),
+                        jnp.asarray(keys.astype(np.int32)),
+                        jnp.asarray(valid))
+        assert (np.asarray(got_pairs) == want).all()
+        assert (np.asarray(got) == want).all()
+    assert _set_reference(vals, keys, kv).any()
