@@ -19,6 +19,17 @@ the program. It follows the semantics of the program's query path:
 6. ranking: the ``min(top_k, videos)`` videos with most landings (ties to
    the lower video), those with at least one.
 
+Where the configuration has a verifier, step 3 keeps only the rows it
+accepted. The reference does not judge the verifier's answers here (the
+harness compares a sample of its margins with a plain model apart): it
+takes the verdicts the engine received, as the harness recorded them
+(:func:`row_verdicts`). A row the verifier was asked about more than once
+may have been answered differently each time (a verdict can change with
+the batch it was computed in), so a row counts as accepted for the fewest
+candidates where every verdict said yes, and for the most where any did;
+a row it was never asked about counts as rejected for the fewest and
+accepted for the most.
+
 Scores are exact: float64 dot products of the stored float32 vectors. The
 program computes them in float32, which rounds; where the k-th best score
 or a threshold lies within the rounding bound ``score_tol`` of another
@@ -31,7 +42,7 @@ after matching is monotone in its candidates, so a right answer's
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -109,13 +120,16 @@ class Reference:
     """Answers over a world's rows. ``entity_scores`` maps an entity text
     to its exact (N,) scores over the entity rows (see
     :func:`exact_scores`); ``pred_scores`` maps a relationship text to its
-    exact (P,) scores over the predicate labels."""
+    exact (P,) scores over the predicate labels. ``accepted``, where a
+    verifier judged the rows, is the (lower, upper) pair of per-row masks
+    of :func:`row_verdicts`."""
 
     def __init__(self, rows: np.ndarray, videos: int, frames: int,
                  entities_per_video: int,
                  entity_scores: Dict[str, np.ndarray],
                  pred_scores: Dict[str, np.ndarray],
-                 score_tol: float = SCORE_TOL):
+                 score_tol: float = SCORE_TOL,
+                 accepted: Optional[Tuple[np.ndarray, np.ndarray]] = None):
         self.videos, self.frames = videos, frames
         self.vid = rows[:, 0].astype(np.int64)
         self.fid = rows[:, 1].astype(np.int64)
@@ -127,6 +141,7 @@ class Reference:
         self.entity_scores = entity_scores
         self.pred_scores = pred_scores
         self.tol = score_tol
+        self.accepted = accepted
 
     def candidates(self, q: dict) -> Tuple[Dict, Dict]:
         """Per entity text and per relationship text: (lower, upper) masks."""
@@ -139,6 +154,30 @@ class Reference:
                  for f in q["frames"] for _, p, _ in f}
         return ents, preds
 
+    def triple_rows(self, q: dict, triple: tuple, side: int, ents: Dict,
+                    preds: Dict) -> np.ndarray:
+        """Indices of the rows the triple filter selects for ``triple``
+        (subject, relationship text, object), with the lower (``side`` 0)
+        or upper (1) candidate sets, before any verdict."""
+        a, p, b = triple
+        sa = ents[q["entities"][a]][side]
+        ob = ents[q["entities"][b]][side]
+        out = []
+        for pid in np.nonzero(preds[p][side])[0]:
+            r = self.by_pred[pid] if pid < len(self.by_pred) \
+                else np.zeros(0, np.int64)
+            out.append(r[sa[self.subj[r]] & ob[self.obj[r]]])
+        return np.concatenate(out) if out else np.zeros(0, np.int64)
+
+    def candidate_rows(self, q: dict) -> np.ndarray:
+        """Indices of every row the triple filter selects for some triple
+        of ``q`` with the lower candidate sets: the rows a verifier that
+        checks every candidate must have been asked about."""
+        ents, preds = self.candidates(q)
+        return np.unique(np.concatenate(
+            [self.triple_rows(q, t, 0, ents, preds)
+             for f in q["frames"] for t in f]))
+
     def end_frames(self, q: dict, side: int, ents: Dict, preds: Dict
                    ) -> np.ndarray:
         """(V, F) landings of the last frame spec, with the lower
@@ -147,26 +186,23 @@ class Reference:
         maps = []
         for f in q["frames"]:
             fmap = np.ones((self.videos, self.frames), bool)
-            for a, p, b in f:
-                key = (a, p, b)
-                if key not in cache:
-                    sa = ents[q["entities"][a]][side]
-                    ob = ents[q["entities"][b]][side]
+            for t in map(tuple, f):
+                if t not in cache:
+                    r = self.triple_rows(q, t, side, ents, preds)
+                    if self.accepted is not None:
+                        r = r[self.accepted[side][r]]
                     bm = np.zeros((self.videos, self.frames), bool)
-                    for pid in np.nonzero(preds[p][side])[0]:
-                        r = self.by_pred[pid] if pid < len(self.by_pred) \
-                            else np.zeros(0, np.int64)
-                        r = r[sa[self.subj[r]] & ob[self.obj[r]]]
-                        bm[self.vid[r], self.fid[r]] = True
-                    cache[key] = bm
-                fmap &= cache[key]
+                    bm[self.vid[r], self.fid[r]] = True
+                    cache[t] = bm
+                fmap &= cache[t]
             maps.append(fmap)
         return chain(maps, q["min_gaps"])
 
     def answer(self, q: dict) -> Tuple[np.ndarray, np.ndarray]:
         ents, preds = self.candidates(q)
         lo = self.end_frames(q, 0, ents, preds)
-        exact = all((a == b).all() for a, b in ents.values()) and all(
+        exact = self.accepted is None and all(
+            (a == b).all() for a, b in ents.values()) and all(
             (a == b).all() for a, b in preds.values())
         hi = lo if exact else self.end_frames(q, 1, ents, preds)
         return lo, hi
@@ -197,13 +233,44 @@ class Reference:
         below = int((lo & ~ef).sum())
         above = int((ef & ~hi).sum())
         if below or above:
+            def at(m):   # the first few (video, frame) pairs of a mask
+                return [tuple(map(int, x)) for x in np.argwhere(m)[:3]]
             return Verdict(False, exact, f"end_frames: {below} landings "
-                           f"missing, {above} beyond what may land")
+                           f"missing {at(lo & ~ef)}, {above} beyond what "
+                           f"may land {at(ef & ~hi)}")
         want = rank(ef, q["top_k"])
         if (list(segments), list(scores)) != want:
             return Verdict(False, exact, f"ranking {segments[:8]}/"
                            f"{scores[:8]} != {want[0][:8]}/{want[1][:8]}")
         return Verdict(True, exact)
+
+
+def row_keys(*arrays: np.ndarray) -> List[np.ndarray]:
+    """One int64 key per (vid, fid, sid, rl, oid) row of each array, equal
+    where the rows are: the same radix for every array given."""
+    dims = np.max([a.max(axis=0) for a in arrays if len(a)], axis=0) + 1
+    return [np.ravel_multi_index(a.T.astype(np.int64), dims)
+            if len(a) else np.zeros(0, np.int64) for a in arrays]
+
+
+def row_verdicts(rows: np.ndarray, asked: np.ndarray, said: np.ndarray
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Per row of ``rows`` (M, 5): (lower, upper, seen) masks from the
+    verifier's answers ``said`` (n,) to the rows ``asked`` (n, 5), one
+    entry per time a row was asked about. ``lower``: asked, and every
+    answer yes; ``upper``: never asked, or some answer yes."""
+    if not len(asked):
+        none = np.zeros(len(rows), bool)
+        return none, ~none, none
+    k_rows, k_asked = row_keys(rows, asked)
+    uniq, inv = np.unique(k_asked, return_inverse=True)
+    every = np.ones(len(uniq), bool)
+    some = np.zeros(len(uniq), bool)
+    np.logical_and.at(every, inv, said)
+    np.logical_or.at(some, inv, said)
+    pos = np.minimum(np.searchsorted(uniq, k_rows), len(uniq) - 1)
+    seen = uniq[pos] == k_rows
+    return seen & every[pos], ~seen | some[pos], seen
 
 
 def exact_scores(bank_rows, queries: np.ndarray, approx: np.ndarray,

@@ -3,17 +3,63 @@
     python3 -m bench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
     JAX_PLATFORMS=cpu python3 -m bench.run --workload <cell> --rehearse
 
-Everything happens in this one process: the world, the stores and the
-engine are built from the seed, every program shape the traffic can reach
-is warmed up, then queries are offered to ``ServingRuntime`` for
-``--seconds`` and drained. ``setup_s`` runs from the start of this module to
-the first due query. What is served is then checked against the plain
-reference (``bench/reference.py``), once the device memory peak has been
-read and the program's state freed.
+Everything happens in this one process: the world, the stores, the
+verifier where the configuration has one, and the engine are built from the
+seed, every program shape the traffic can reach is warmed up, then queries
+are offered to ``ServingRuntime`` for ``--seconds`` and drained: in an open
+loop at the mix's arrival times, or in a closed loop by the mix's
+``clients`` (``bench/traffic.py``). ``setup_s`` runs from the start of this
+module to the first due query. What is served is then checked against the
+plain reference (``bench/reference.py``), once the device memory peak has
+been read and the program's state freed.
 
 The cell, its configuration (``bench/configs/<config>.json``), its traffic
 mix (``bench/traffic/<mix>.json``) and its per-layer metrics
 (``bench/metrics/<metric>.py``) are found by name in ``BENCHMARK.json``.
+
+A configuration may hold a ``verifier`` block: the VLM the engine asks
+about each candidate row. Its ``plugin`` names ``bench/models/<plugin>.py``,
+found by name as a metric reader is; the block's other keys are those the
+plug-in lists in ``KEYS``, and another key is refused. A plug-in provides:
+
+* ``build(cfg, *, world, stores, seed, rehearse)``: the verifier the engine
+  is given, made through the program's own classes with weights made on the
+  device from the seed, at a reduced size under ``rehearse``. It has
+  ``verify(rows) -> bool (M,)``, a ``calls`` count, and ``margins(rows)``,
+  the yes-minus-no logit margins that its ``verify`` computes and
+  thresholds at 0 (as ``repro.core.refine.VLMVerifier`` does); a verifier
+  without ``margins``, or whose ``verify`` does not go through it, is
+  refused. Its ``cfg`` attribute, where it has one, is the model's
+  ``ModelConfig``, and ``batch_size`` the rows it pads a call to;
+* ``reference_margins(cfg, rows, *, world, seed, rehearse)``: the plain
+  float32 margins of ``rows`` (M, 5), float64 (M,), computed apart from the
+  program from the seed alone; it runs after the program's state is freed;
+* ``TOLERANCE``: the margin error allowed, as a share of the reference
+  margins' RMS, with its reason beside it.
+
+Set-up builds the stores, frees what ``append_stores`` left behind, and only
+then builds the verifier, so that the model's weights never meet that
+transient; the bytes in use after each step go to standard error.
+``VerifierRecorder`` keeps, for every call in the window, the rows asked
+about, the verdicts the engine received, the margins and the call's host
+time. Then the check takes those verdicts as given (a row counts as
+accepted for the fewest candidates where every verdict said yes, for the
+most where any did, see ``bench/reference.py``) and adds three numbers:
+``unverified_rows`` (limit 0), rows that queries with ``verify_budget`` 0
+must have had verified and that the verifier never saw;
+``verifier_margin_error``, the largest distance of a recorded margin from
+the plug-in's reference over a seed-drawn sample of at most
+``MARGIN_SAMPLE`` rows, as a share of the reference's RMS (limit
+``TOLERANCE``); and ``verifier_sign_errors`` (limit 0), recorded
+verdicts of those rows that disagree with the reference's sign where the
+reference lies more than ``TOLERANCE`` x RMS from 0. A configuration
+without a verifier gives the engine none and keeps its four checks.
+
+Every wrong answer is described on standard error: the query, when it was
+due, the size of the batch it ran in, and what differed from the reference
+(the first landings missing or beyond what may land, or the ranking). The
+first ``WRONG_SHOWN`` also go into the result line, under ``wrong_detail``,
+just before ``checks``.
 
 Without a TPU, or with fewer chips than the cell asks for, the run prints no
 result and exits 2. ``--rehearse`` runs a cell end to end on the CPU at a
@@ -36,7 +82,7 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Dict, Iterator, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -46,10 +92,15 @@ sys.path.insert(0, str(ROOT / "src"))
 
 # queries compared with the reference at most, drawn from the seed
 MAX_CHECKED = 600
+# wrong answers described in the result line (all go to standard error)
+WRONG_SHOWN = 2
 # a query not answered this long after the window closed never comes
 GRACE_S = 60.0
 # largest batch ServingRuntime's default BatchBudget admits
 MAX_BATCH = 8
+# distinct verified rows whose margins the check recomputes, drawn from the
+# seed: each is a prefill of the reference model
+MARGIN_SAMPLE = 48
 REHEARSAL = {"videos": 40, "frames_per_video": 12, "embedding_dim": 64,
              "entity_capacity": 256, "relationship_capacity": 8192,
              "store_segments": 4, "cold_segments": 1, "sessions": 2}
@@ -69,20 +120,32 @@ CFG_KEYS = {"videos", "frames_per_video", "entities_per_video", "classes",
             "colors", "accessories", "predicates", "description_zipf",
             "spurious_prob", "embedding_dim", "embedding_noise",
             "entity_capacity", "relationship_capacity", "store_segments",
-            "cold_segments", "engine", "sessions",
+            "cold_segments", "engine", "sessions", "verifier",
             "name", "source", "deployment", "reduced", "assumed"}
 ENGINE_KEYS = {"use_kernels", "search_mode"}
+# verifier plug-ins, found by the name a configuration's block gives
+MODELS = BENCH / "models"
+
+
+def spec_file() -> Path:
+    """``BENCHMARK.json``; a configuration's ``file`` is relative to the
+    directory that holds it."""
+    return ROOT / "BENCHMARK.json"
 
 
 def load_cell(name: str) -> dict:
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec = json.loads(spec_file().read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     cfg_entry = next(c for c in spec["configs"] if c["name"] == cell["config"])
-    cfg = json.loads((ROOT / cfg_entry["file"]).read_text())
+    cfg = json.loads((spec_file().parent / cfg_entry["file"]).read_text())
     unknown = (set(cfg) - CFG_KEYS) | (set(cfg["engine"]) - ENGINE_KEYS)
+    if "verifier" in cfg:
+        block = cfg["verifier"]
+        unknown |= {f"verifier.{k}" for k in set(block) - {"plugin"}
+                    - set(load_plugin(block["plugin"]).KEYS)}
     if unknown:
         raise SystemExit(f"{cfg_entry['file']}: keys {sorted(unknown)} are "
                          f"not implemented")
@@ -98,13 +161,22 @@ def load_cell(name: str) -> dict:
             "run_seconds": spec["run_seconds"]}
 
 
-def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
+def _load(path: Path, prefix: str):
     mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+        f"{prefix}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    return _load(BENCH / "metrics" / f"{name}.py", "bench_metric").read
+
+
+def load_plugin(name: str):
+    """The verifier plug-in ``MODELS/<name>.py`` (see the module's
+    docstring for what it provides)."""
+    return _load(MODELS / f"{name}.py", "bench_model")
 
 
 # ---------------------------------------------------------------------------
@@ -223,7 +295,8 @@ def to_query(q: dict):
         constraints=tuple(TemporalConstraint(j, j + 1, min_gap=g)
                           for j, g in enumerate(q["min_gaps"])),
         top_k=q["top_k"], text_threshold=q["text_threshold"],
-        predicate_top_m=q["predicate_top_m"])
+        predicate_top_m=q["predicate_top_m"],
+        verify_budget=q.get("verify_budget", 0))
 
 
 def bytes_in_use(devices) -> int:
@@ -276,6 +349,86 @@ class SearchRecorder:
                 for q, k, (s, i) in self.calls]
 
 
+@dataclass
+class VerifierCall:
+    """One call of the verifier: the rows asked about (M, 5), the verdicts
+    the engine received (M,), the margins the verifier computed for them
+    (M,), and the call's host seconds. M is the call's batch of rows; a
+    verifier pads it to ``batch_size``."""
+
+    rows: np.ndarray
+    verdicts: np.ndarray
+    margins: np.ndarray
+    seconds: float
+
+
+@dataclass
+class VerifierRun:
+    """What the window's verifier did, for the check and the readers: every
+    call, the model (a ``repro.configs`` ``ModelConfig``, or None) and the
+    rows a call is padded to (or None)."""
+
+    calls: List[VerifierCall]
+    model: object = None
+    batch_size: Optional[int] = None
+
+    def rows(self) -> tuple:
+        """(rows asked (n, 5), verdicts (n,), margins (n,)) over every
+        call."""
+        if not self.calls:
+            return (np.zeros((0, 5), np.int64), np.zeros(0, bool),
+                    np.zeros(0))
+        return tuple(np.concatenate([getattr(c, f) for c in self.calls])
+                     for f in ("rows", "verdicts", "margins"))
+
+
+class VerifierRecorder:
+    """Stands between the engine and the verifier and keeps every call (see
+    :class:`VerifierCall`), for the check and the readers: the verdicts
+    alone would not show a verifier that answers from a model of lower
+    precision. The recorder taps ``margins`` on the verifier itself, so that
+    the margins kept are those its ``verify`` thresholded, computed once. A
+    verifier without ``margins`` is refused here, and a call of ``verify``
+    that does not compute its rows' margins through it raises."""
+
+    def __init__(self, verifier):
+        if not callable(getattr(verifier, "margins", None)):
+            raise SystemExit(f"verifier {type(verifier).__name__} has no "
+                             f"margins(rows): a plug-in's verifier must "
+                             f"expose the margins its verify thresholds")
+        self.real = verifier
+        self.log: List[VerifierCall] = []
+        self._seen: list = []
+        margins = verifier.margins
+
+        def tap(rows):
+            out = margins(rows)
+            self._seen.append(np.asarray(out, np.float64))
+            return out
+        verifier.margins = tap
+
+    @property
+    def calls(self) -> int:
+        return getattr(self.real, "calls", 0)
+
+    def verify(self, rows: np.ndarray) -> np.ndarray:
+        self._seen = []
+        t0 = time.perf_counter()
+        out = self.real.verify(rows)
+        seconds = time.perf_counter() - t0
+        rows = np.array(rows, np.int64)
+        m = np.concatenate(self._seen) if self._seen else np.zeros(0)
+        if len(m) != len(rows):
+            raise RuntimeError(f"{type(self.real).__name__}.verify gave "
+                               f"{len(rows)} verdicts from {len(m)} margins")
+        self.log.append(VerifierCall(rows, np.array(out, bool), m, seconds))
+        return out
+
+    def host(self) -> VerifierRun:
+        return VerifierRun(self.log, getattr(self.real, "cfg", None),
+                           getattr(self.real, "batch_size", None))
+
+
 # ---------------------------------------------------------------------------
 # the window
 # ---------------------------------------------------------------------------
@@ -300,6 +453,7 @@ class RunData:
     trace_window: tuple = ()
     peaks: dict = field(default_factory=dict)
     search_least_bytes: float = 0.0      # one scan of the text bank
+    verifier: Optional[VerifierRun] = None   # None: no verifier
 
     def done(self) -> List[Served]:
         return [s for s in self.served if s.ticket is not None
@@ -340,6 +494,45 @@ def open_loop(rt: SpannedRuntime, plan: List[dict], seconds: float,
     return served, t0, time.perf_counter()
 
 
+def closed_loop(rt: SpannedRuntime, queries: Iterator[dict], clients: int,
+                seconds: float, sessions: List[str]) -> tuple:
+    """``clients`` clients, each submitting the next query of ``queries``
+    when its previous one is answered (or refused), until ``seconds`` have
+    passed; then drain. A query's due time is its submission time, and
+    ``q["session"]`` its client. Returns (served, t0, t_end)."""
+    t0 = time.perf_counter()
+    close, stop = t0 + seconds, t0 + seconds + GRACE_S
+    served: List[Served] = []
+    current: List[Optional[Served]] = [None] * clients
+
+    def idle(s):
+        return s is None or s.rejected or s.ticket.done
+    while True:
+        now = time.perf_counter()
+        if now < close:
+            for c in range(clients):
+                if not idle(current[c]):
+                    continue
+                s = Served(dict(next(queries), session=c), 0.0)
+                query = to_query(s.q)
+                s.due = s.submitted = time.perf_counter()
+                out = rt.submit(query, sessions[c % len(sessions)])
+                if getattr(out, "rejected", False):
+                    s.rejected = True
+                else:
+                    s.ticket = out
+                served.append(s)
+                current[c] = s
+        if now > stop:
+            break
+        if rt.rt.queue_depth:
+            rt.tick()
+            continue
+        if now >= close and all(map(idle, current)):
+            break
+    return served, t0, time.perf_counter()
+
+
 def percentile(x: np.ndarray, p: float) -> float:
     return float(np.percentile(x, p, method="inverted_cdf"))
 
@@ -348,10 +541,12 @@ def percentile(x: np.ndarray, p: float) -> float:
 # the check
 # ---------------------------------------------------------------------------
 def make_reference(cfg: dict, world, seed: int, queries: List[dict],
-                   searched: Optional[Dict[str, int]] = None):
+                   searched: Optional[Dict[str, int]] = None,
+                   accepted: Optional[tuple] = None):
     """The reference over the world, with exact scores for every text
     ``queries`` ask for, exact near each query's k-th best and near the
-    largest k of ``searched`` (text -> k). Runs after the program's state
+    largest k of ``searched`` (text -> k), and the verifier's per-row
+    ``accepted`` masks where it has one. Runs after the program's state
     is freed: it makes the entity bank again from the seed, on the
     device."""
     import jax
@@ -386,7 +581,8 @@ def make_reference(cfg: dict, world, seed: int, queries: List[dict],
     return Reference(world.rows, world.videos, world.frames,
                      world.entities_per_video,
                      dict(zip(texts, scores)),
-                     {t: pv @ v for t, v in zip(rel_texts, rv)})
+                     {t: pv @ v for t, v in zip(rel_texts, rv)},
+                     accepted=accepted)
 
 
 def search_check(ref, searches: list, texts_of: Dict[bytes, str]) -> dict:
@@ -411,8 +607,48 @@ def search_check(ref, searches: list, texts_of: Dict[bytes, str]) -> dict:
             "search_rank_errors": bad}
 
 
+def margin_sample(asked: np.ndarray, seed: int) -> np.ndarray:
+    """At most ``MARGIN_SAMPLE`` distinct rows of ``asked``, drawn from the
+    seed, in row order."""
+    uniq = np.unique(asked, axis=0)
+    rng = np.random.default_rng([seed, 0x3A291])
+    return uniq[np.sort(rng.choice(len(uniq), min(MARGIN_SAMPLE, len(uniq)),
+                                   replace=False))]
+
+
+def margin_check(verifier: VerifierRun, plugin, cfg: dict, world, seed: int,
+                 rehearse: bool) -> dict:
+    """The recorded margins and verdicts of :func:`margin_sample`'s rows
+    against the plug-in's reference margins (see the module's
+    docstring)."""
+    from bench.reference import row_keys
+    tol = float(plugin.TOLERANCE)
+    asked, said, got = verifier.rows()
+    out = {"margin_rows": 0, "margin_rms": 0.0, "tolerance": tol,
+           "margin_error": 0.0, "sign_errors": 0}
+    if not len(asked):
+        return out
+    sample = margin_sample(asked, seed)
+    want = np.asarray(plugin.reference_margins(
+        cfg, sample, world=world, seed=seed, rehearse=rehearse), np.float64)
+    rms = float(np.sqrt(np.mean(want ** 2)))
+    k_sample, k_asked = row_keys(sample, asked)
+    at = np.searchsorted(k_sample, k_asked)
+    hit = k_sample[np.minimum(at, len(k_sample) - 1)] == k_asked
+    ref = want[at[hit]]
+    err = float(np.max(np.abs(got[hit] - ref)))
+    out.update(margin_rows=len(sample), margin_rms=rms,
+               # a zero RMS would need every sampled reference margin to be 0
+               margin_error=err / rms if rms > 0 else float(err > 0),
+               sign_errors=int(np.sum((said[hit] != (ref > 0))
+                                      & (np.abs(ref) > tol * rms))))
+    return out
+
+
 def check(cfg: dict, world, seed: int, served: List[Served],
-          searches: list, texts_of: Dict[bytes, str]) -> dict:
+          searches: list, texts_of: Dict[bytes, str],
+          verifier: Optional[VerifierRun] = None, plugin=None,
+          rehearse: bool = False) -> dict:
     done = [s for s in served if s.ticket is not None and s.ticket.done
             and s.ticket.error is None]
     rng = np.random.default_rng([seed, 0xC4EC])
@@ -425,21 +661,39 @@ def check(cfg: dict, world, seed: int, served: List[Served],
         for row in q:
             t = texts_of[row.tobytes()]
             searched[t] = max(searched.get(t, 0), k)
-    ref = make_reference(cfg, world, seed, [s.q for s in done], searched)
-    wrong, exact, first_wrong = 0, 0, ""
+    accepted = seen = None
+    if verifier is not None:
+        from bench.reference import row_verdicts
+        lo, hi, seen = row_verdicts(world.rows, *verifier.rows()[:2])
+        accepted = (lo, hi)
+    ref = make_reference(cfg, world, seed, [s.q for s in done], searched,
+                         accepted)
+    wrong, exact, wrongs = 0, 0, []
     for s in done:
         r = s.ticket.result
         v = ref.check(s.q, r.segments, r.scores, r.end_frames)
         exact += v.exact
         if not v.ok:
             wrong += 1
-            if not first_wrong:
-                from bench.traffic import describe
-                first_wrong = f"{describe(s.q)}: {v.detail}"
-    return {"checked": len(done), "wrong": wrong, "exact": exact,
-            "first_wrong": first_wrong, "tol": ref.tol,
-            **search_check(ref, searches, texts_of),
-            "seconds": time.perf_counter() - t0}
+            from bench.traffic import describe
+            wrongs.append(f"{describe(s.q)} due {s.q.get('due', 0.0):.3f} "
+                          f"s, in a batch of "
+                          f"{getattr(s.ticket, 'coalesced_with', 0)}: "
+                          f"{v.detail}")
+    out = {"checked": len(done), "wrong": wrong, "exact": exact,
+           "wrongs": wrongs, "tol": ref.tol,
+           **search_check(ref, searches, texts_of)}
+    if verifier is not None:
+        need = np.zeros(len(world.rows), bool)
+        for s in done:
+            if s.q.get("verify_budget", 0) == 0:
+                need[ref.candidate_rows(s.q)] = True
+        out["verifier"] = {
+            "rows": int(sum(len(c.rows) for c in verifier.calls)),
+            "unverified_rows": int(np.sum(need & ~seen)),
+            **margin_check(verifier, plugin, cfg, world, seed, rehearse)}
+    out["seconds"] = time.perf_counter() - t0
+    return out
 
 
 def verdict(res: dict, failed: int) -> tuple:
@@ -451,8 +705,18 @@ def verdict(res: dict, failed: int) -> tuple:
                                      "limit": 0},
               "search_score_error": {"value": res["search_score_error"],
                                      "limit": res["tol"]}}
-    correct = (res["search_rows"] > 0
-               and all(v["value"] <= v["limit"] for v in checks.values()))
+    correct = res["search_rows"] > 0
+    v = res.get("verifier")
+    if v is not None:
+        checks["unverified_rows"] = {"value": v["unverified_rows"],
+                                     "limit": 0}
+        checks["verifier_margin_error"] = {"value": v["margin_error"],
+                                           "limit": v["tolerance"]}
+        checks["verifier_sign_errors"] = {"value": v["sign_errors"],
+                                          "limit": 0}
+        correct = correct and v["rows"] > 0
+    correct = correct and all(c["value"] <= c["limit"]
+                              for c in checks.values())
     return correct, checks
 
 
@@ -460,7 +724,9 @@ def sweep(rt, mix, args, seconds, world, preds, names, compiles) -> int:
     """Offer the mix at each rate of ``--sweep`` for ``seconds`` and print,
     per rate, the latencies of the first and second half of the arrivals
     and how long the backlog took to drain after the window: a rate above
-    the knee leaves a backlog that grows through the window."""
+    the knee leaves a backlog that grows through the window. A closed-loop
+    mix is offered open loop here; its warm-up reached batches of at most
+    ``clients`` queries, so larger ones compile in the window (counted)."""
     from bench import traffic
     for j, rate in enumerate(float(r) for r in args.sweep.split(",")):
         m = dict(mix, rate_per_s=rate)
@@ -548,10 +814,23 @@ def execute(args, warmup: bool = True) -> tuple:
         f"{world.entities_per_video} entities, {len(world.rows)} "
         f"relationship rows, {time.perf_counter() - T_START:.3f} s")
     stores = build_stores(cfg, world, args.seed)
+    log(f"device bytes in use after the stores: {bytes_in_use(devices)}")
+    plugin = verifier = None
+    if "verifier" in cfg:
+        # the model goes on the device only once append_stores' copies of
+        # the banks are freed: the two together need not fit
+        gc.collect()
+        log(f"device bytes in use once freed: {bytes_in_use(devices)}")
+        plugin = load_plugin(cfg["verifier"]["plugin"])
+        verifier = VerifierRecorder(plugin.build(
+            cfg, world=world, stores=stores, seed=args.seed,
+            rehearse=args.rehearse))
+        log(f"device bytes in use with the verifier: "
+            f"{bytes_in_use(devices)}, {time.perf_counter() - T_START:.3f} s")
     eng = cfg["engine"]
     embedder = BenchEmbedder(cfg["embedding_dim"], salt_of(args.seed))
     session = open_video_store(
-        stores, embedder, verifier=None, use_kernels=eng["use_kernels"],
+        stores, embedder, verifier=verifier, use_kernels=eng["use_kernels"],
         search_mode=eng["search_mode"])
     recorder = SearchRecorder(session.engine)
     registry = SessionRegistry(session.engine)
@@ -564,9 +843,17 @@ def execute(args, warmup: bool = True) -> tuple:
         f"{time.perf_counter() - T_START:.3f} s")
 
     mix = traffic.load_mix(cell["traffic"])
+    clients = mix.get("clients")
     preds = list(cfg["predicates"])
+    if verifier is not None:
+        # also shows, before any query, that verify goes through margins
+        t_batch = time.perf_counter()
+        verifier.verify(world.rows[:1])
+        log(f"first verifier call: {time.perf_counter() - t_batch:.3f} s")
     n_warm = 0
-    for batch in traffic.warmup_batches(mix, MAX_BATCH if warmup else 0,
+    # a closed loop never has more queries in a batch than clients
+    max_batch = min(MAX_BATCH, clients or MAX_BATCH)
+    for batch in traffic.warmup_batches(mix, max_batch if warmup else 0,
                                         world, preds, args.seed):
         for i, q in enumerate(batch):
             rt.submit(to_query(q), names[i % len(names)])
@@ -576,7 +863,10 @@ def execute(args, warmup: bool = True) -> tuple:
         log(f"warm-up batch {n_warm}: {len(batch)} queries, "
             f"{time.perf_counter() - t_batch:.3f} s")
         n_warm += 1
-    plan = traffic.schedule(mix, seconds, args.seed, world, preds)
+    if clients:
+        plan = traffic.stream(mix, args.seed, world, preds)
+    else:
+        plan = traffic.schedule(mix, seconds, args.seed, world, preds)
     if args.sweep:
         return None, sweep(rt, mix, args, seconds, world, preds, names,
                            compiles)
@@ -590,9 +880,14 @@ def execute(args, warmup: bool = True) -> tuple:
     batches0 = rt.rt.metrics.batches
     compiles0 = compiles.n
     recorder.calls.clear()
+    if verifier is not None:
+        verifier.log.clear()
     rt.serving_bytes = warm_bytes = bytes_in_use(devices)
     setup_s = time.perf_counter() - T_START
-    served, t0, t_end = open_loop(rt, plan, seconds, names)
+    if clients:
+        served, t0, t_end = closed_loop(rt, plan, clients, seconds, names)
+    else:
+        served, t0, t_end = open_loop(rt, plan, seconds, names)
     if args.trace:
         jax.profiler.stop_trace()
     window_compiles = compiles.n - compiles0
@@ -604,7 +899,8 @@ def execute(args, warmup: bool = True) -> tuple:
     smallest = "int4" if stores.entities.text_i4 is not None else "int8"
     least = topk_least_bytes(valid_rows, cfg["embedding_dim"], smallest)
     searches = recorder.host()
-    del rt, registry, session, stores, recorder
+    verified = verifier.host() if verifier is not None else None
+    del rt, registry, session, stores, recorder, verifier
     gc.collect()
 
     attempted = len(served)
@@ -625,7 +921,8 @@ def execute(args, warmup: bool = True) -> tuple:
         f"{warm_bytes} after warm-up, at most {serving_bytes} between ticks")
 
     run = RunData(cfg=cfg, served=served, window_s=t_end - t0,
-                  batches=batches, peaks=pk, search_least_bytes=least)
+                  batches=batches, peaks=pk, search_least_bytes=least,
+                  verifier=verified)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": int(mem_peak),
               "serving_bytes_in_use": int(serving_bytes)}
@@ -655,16 +952,29 @@ def execute(args, warmup: bool = True) -> tuple:
             metrics[m["name"]] = {"value": e2e[m["name"]],
                                   "unit": units[m["name"]]}
 
-    res = check(cfg, world, args.seed, served, searches, embedder.texts_of)
+    res = check(cfg, world, args.seed, served, searches, embedder.texts_of,
+                verified, plugin, args.rehearse)
     log(f"check: {res['checked']} answers compared with the reference in "
         f"{res['seconds']:.3f} s, {res['exact']} with one right answer, "
-        f"{res['wrong']} wrong {res['first_wrong']}; {len(searches)} "
-        f"searches, {res['search_rows']} rows")
+        f"{res['wrong']} wrong; {len(searches)} searches, "
+        f"{res['search_rows']} rows")
+    for w in res["wrongs"]:
+        log(f"wrong answer: {w}")
+    if verified is not None:
+        v = res["verifier"]
+        log(f"verifier: {len(verified.calls)} calls, {v['rows']} rows; "
+            f"margins of {v['margin_rows']} rows recomputed, RMS "
+            f"{v['margin_rms']:.6g}")
     correct, checks = verdict(res, failed)
     for k, v in checks.items():
         log(f"compared {k}: {v['value']} (limit {v['limit']})")
     out = {"correct": correct, "attempted": attempted, "failed": failed,
-           "metrics": metrics, "device": device, **out, "checks": checks}
+           "metrics": metrics, "device": device, **out}
+    if res["wrongs"]:
+        # the query, when it was due, the batch it ran in and what differed,
+        # so that a refused run's last line says which answer was wrong
+        out["wrong_detail"] = res["wrongs"][:WRONG_SHOWN]
+    out["checks"] = checks
     if args.rehearse:
         log(f"rehearsal (CPU, not a chip measurement): {json.dumps(out)}")
         return out, 1

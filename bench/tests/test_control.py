@@ -10,6 +10,7 @@ has to come out with ``correct`` false; unbroken it comes out true.
 import argparse
 
 import numpy as np
+import pytest
 
 from bench import run, traffic
 from bench.control import control_run
@@ -20,7 +21,7 @@ SEED = 2 ** 31 + 77
 
 
 def test_bfloat16_control_is_wrong():
-    cfg = dict(run.load_cell("archive.interactive")["cfg"])
+    cfg = dict(run.load_cell("archive.chains")["cfg"])
     cfg.update(videos=400, entity_capacity=4096,
                relationship_capacity=1 << 17)
     world = build_world(cfg, SEED)
@@ -36,7 +37,19 @@ def test_bfloat16_control_is_wrong():
     assert checks["search_rank_errors"]["value"] > 0, out
 
 
-def rehearse(cell="archive.interactive"):
+@pytest.fixture(autouse=True)
+def busy_rehearsal(monkeypatch):
+    """The chains mix offers 0.3 queries/s, so a 2 s rehearsal would hold
+    none; offered at 6 queries/s it holds a dozen."""
+    mix = traffic.load_mix
+
+    def faster(name):
+        m = mix(name)
+        return dict(m, rate_per_s=6.0) if name == "chains" else m
+    monkeypatch.setattr(traffic, "load_mix", faster)
+
+
+def rehearse(cell="archive.chains"):
     args = argparse.Namespace(workload=cell, seed=SEED, seconds=2.0,
                               trace=0, rehearse=True, sweep="")
     out, rc = run.execute(args, warmup=False)
@@ -47,6 +60,7 @@ def rehearse(cell="archive.interactive"):
 def test_sound_rehearsal_is_correct():
     out = rehearse()
     assert out["correct"] and out["failed"] == 0, out
+    assert out["attempted"] >= 8, out
     assert out["checks"]["wrong_answers"]["value"] == 0
 
 
@@ -70,6 +84,11 @@ def test_an_answer_altered_where_it_is_produced(monkeypatch):
     out = rehearse()
     assert not out["correct"]
     assert out["checks"]["wrong_answers"]["value"] > 0
+    # the result line says which answer was wrong, and keeps checks last
+    shown = out["wrong_detail"]
+    assert 0 < len(shown) <= run.WRONG_SHOWN
+    assert "in a batch of " in shown[0] and "end_frames:" in shown[0], shown
+    assert list(out)[-1] == "checks"
 
 
 def test_half_of_the_queries_left_out(monkeypatch):

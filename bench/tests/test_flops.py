@@ -1,17 +1,20 @@
+import dataclasses
+
 import pytest
 
-from bench.flops import topk_least_bytes, verifier_prefill_flops
+from bench.flops import prefill_flops, topk_least_bytes
 from bench.peaks import peaks
+from repro.configs import get_config
 
 
 def test_verifier_prefill_at_sixteen_by_1048_on_fourteen_layers():
-    f = verifier_prefill_flops(batch=16, seq=1048, layers=14, d_model=3584,
-                               heads=28, kv_heads=4, head_dim=128,
-                               d_ff=18944, vocab=152064)
+    cfg = dataclasses.replace(get_config("qwen2.5-vl-7b"), num_layers=14)
+    f = prefill_flops(cfg, batch=16, seq=1048)
     assert f["matmul"] / 1e12 == pytest.approx(109.4, abs=0.1)
     assert f["attention"] / 1e12 == pytest.approx(3.53, abs=0.01)
     assert f["lm_head"] / 1e12 == pytest.approx(0.0174, abs=1e-4)
     assert f["total"] / 1e12 == pytest.approx(113.0, abs=0.1)
+    assert f["total"] / 1e12 == pytest.approx(112.96, abs=0.005)
 
 
 def test_topk_least_bytes_by_smallest_copy():

@@ -16,12 +16,13 @@ def small_cfg(name):
     return cfg
 
 
-@pytest.mark.parametrize("cell", ["archive.interactive", "archive.chains"])
-def test_reference_equals_engine(cell):
+# interactive is the traffic of a cell left out of BENCHMARK.json until
+# lax.top_k keeps ties in index order on the TPU; its mix is still checked
+@pytest.mark.parametrize("mix_name", ["interactive", "chains"])
+def test_reference_equals_engine(mix_name):
     from bench.embed import BenchEmbedder, salt_of
     from repro.session import open_video_store
-    spec = run.load_cell(cell)
-    cfg = small_cfg(cell)
+    cfg = small_cfg("archive.chains")
     world = build_world(cfg, SEED)
     stores = run.build_stores(cfg, world, SEED)
     assert [s.tier for s in stores.segments] == ["cold", "hot", "hot", "hot"]
@@ -29,7 +30,7 @@ def test_reference_equals_engine(cell):
     engine = open_video_store(
         stores, BenchEmbedder(cfg["embedding_dim"], salt_of(SEED)),
         use_kernels=False, search_mode=eng["search_mode"]).engine
-    mix = traffic.load_mix(spec["cell"]["traffic"])
+    mix = traffic.load_mix(mix_name)
     plan = traffic.schedule(dict(mix, rate_per_s=8), 4, SEED, world,
                             list(cfg["predicates"]))
     results = []

@@ -8,7 +8,7 @@ ROOT = Path(__file__).resolve().parents[2]
 
 
 def test_without_a_tpu_no_result_and_nonzero_exit(capsys):
-    rc = run.main(["--workload", "archive.interactive", "--seed",
+    rc = run.main(["--workload", "archive.chains", "--seed",
                    str(2 ** 31 + 5), "--seconds", "1", "--trace", "0"])
     assert rc != 0
     assert capsys.readouterr().out == ""

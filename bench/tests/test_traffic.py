@@ -11,7 +11,7 @@ SEED = 2 ** 31 + 313
 
 
 def world_and_mix(name="interactive"):
-    cfg = dict(run.load_cell("archive.interactive")["cfg"], videos=600)
+    cfg = dict(run.load_cell("archive.chains")["cfg"], videos=600)
     return cfg, build_world(cfg, SEED), traffic.load_mix(name)
 
 
@@ -60,7 +60,7 @@ def test_a_mix_key_the_generator_does_not_do_is_refused(tmp_path,
 def test_a_configuration_key_the_harness_does_not_do_is_refused(
         tmp_path, monkeypatch):
     spec = json.loads((run.ROOT / "BENCHMARK.json").read_text())
-    cfg = dict(run.load_cell("archive.interactive")["cfg"])
+    cfg = dict(run.load_cell("archive.chains")["cfg"])
     cfg["engine"] = dict(cfg["engine"], verifier="qwen25_vl_7b")
     (tmp_path / "bench").mkdir()
     (tmp_path / "bench" / "cfg.json").write_text(json.dumps(cfg))
@@ -68,4 +68,4 @@ def test_a_configuration_key_the_harness_does_not_do_is_refused(
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     monkeypatch.setattr(run, "ROOT", tmp_path)
     with pytest.raises(SystemExit, match="verifier"):
-        run.load_cell("archive.interactive")
+        run.load_cell("archive.chains")

@@ -11,7 +11,8 @@ object; the reference reads the dict itself):
      "top_k": k, "text_threshold": t, "predicate_top_m": m,
      "session": tenant index, "cls": class name}
 
-where subject and object index ``entities``. Every seed gets the same
+where subject and object index ``entities``, and with ``"verify_budget"``
+where the mix gives one. Every seed gets the same
 number of queries of each class and of each ``top_k``, and the same
 arrival times: the seed changes which descriptions and predicates are
 asked for and in which order, not how much work there is or when.
@@ -30,6 +31,22 @@ ranked by its instances in the world, hot ones included, and its
 predicates at random: what many analysts ask about, whether or not the
 archive holds the event.
 
+A mix says how its queries arrive in one of two ways, and a mix file
+names exactly one of them:
+
+* ``rate_per_s``: an open loop (:func:`schedule`), independent analysts
+  whose arrival times do not wait for the system;
+* ``clients``: a closed loop of that many clients (:func:`stream`, driven
+  by ``run.closed_loop``), each submitting its next query when its
+  previous one is answered, so that the system sets the pace. A query's due
+  time is then its submission time, and latency keeps its meaning. Client
+  c is session c, so a closed-loop mix gives no ``sessions``.
+
+``verify_budget``, where a mix gives it, is every query's
+``VMRQuery.verify_budget`` (0: the engine verifies every candidate row in
+one pass; n > 0: the budgeted cascade). It only matters to a configuration
+with a verifier; a mix without it leaves the query's default.
+
 A mix file holds only the keys below; another key is refused, so a mix
 cannot ask for something the generator does not do.
 """
@@ -46,8 +63,9 @@ import numpy as np
 TRAFFIC_DIR = Path(__file__).resolve().parent / "traffic"
 
 
-MIX_KEYS = {"rate_per_s", "sessions", "text_threshold", "predicate_top_m",
-            "top_k", "classes", "instances_per_k"}
+MIX_KEYS = {"rate_per_s", "clients", "verify_budget", "sessions",
+            "text_threshold", "predicate_top_m", "top_k", "classes",
+            "instances_per_k"}
 CLASS_KEYS = {"name", "share", "frames", "shared_triple", "min_gap", "zipf"}
 
 
@@ -58,6 +76,12 @@ def load_mix(name: str) -> dict:
     if unknown:
         raise ValueError(f"traffic mix {name!r}: keys {sorted(unknown)} are "
                          f"not implemented")
+    if ("rate_per_s" in mix) == ("clients" in mix):
+        raise ValueError(f"traffic mix {name!r}: give one of rate_per_s "
+                         f"(open loop) and clients (closed loop)")
+    if "clients" in mix and "sessions" in mix:
+        raise ValueError(f"traffic mix {name!r}: sessions is an open loop's "
+                         f"key; a closed loop's client c is session c")
     return mix
 
 
@@ -205,11 +229,27 @@ def _query(mix: dict, cls: dict, top_k: int, gap: int, texts: List[str],
     frames = [[(0, preds[j], 1)] for j in range(n_frames)]
     if cls.get("shared_triple", False):
         frames[0].append((2, preds[-1], 1))
-    return {"entities": texts, "frames": frames,
-            "min_gaps": [gap] * (n_frames - 1), "top_k": int(top_k),
-            "text_threshold": float(mix["text_threshold"]),
-            "predicate_top_m": int(mix["predicate_top_m"]),
-            "cls": cls["name"]}
+    q = {"entities": texts, "frames": frames,
+         "min_gaps": [gap] * (n_frames - 1), "top_k": int(top_k),
+         "text_threshold": float(mix["text_threshold"]),
+         "predicate_top_m": int(mix["predicate_top_m"]),
+         "cls": cls["name"]}
+    if "verify_budget" in mix:
+        q["verify_budget"] = int(mix["verify_budget"])
+    return q
+
+
+def _draws(mix: dict, n: int, rng: np.random.Generator) -> tuple:
+    """(class index (n,), top_k (n,)) of ``n`` queries: the counts by the
+    mix's shares, in an order that ``rng`` shuffles."""
+    classes = mix["classes"]
+    cls_idx = np.repeat(np.arange(len(classes)),
+                        _counts([c["share"] for c in classes], n))
+    ks, kshares = zip(*mix["top_k"])
+    k_of = np.repeat(np.asarray(ks), _counts(kshares, n))
+    rng.shuffle(cls_idx)
+    rng.shuffle(k_of)
+    return cls_idx, k_of
 
 
 def schedule(mix: dict, seconds: float, seed: int, world,
@@ -225,21 +265,34 @@ def schedule(mix: dict, seconds: float, seed: int, world,
     # queries in a window, which seed bunches them would otherwise decide
     # the latency more than the system does
     due = np.sort(np.random.default_rng(0xA221FA1).random(n) * seconds)
-    classes = mix["classes"]
-    cls_idx = np.repeat(np.arange(len(classes)),
-                        _counts([c["share"] for c in classes], n))
-    ks, kshares = zip(*mix["top_k"])
-    k_of = np.repeat(np.asarray(ks), _counts(kshares, n))
-    rng.shuffle(cls_idx)
-    rng.shuffle(k_of)
+    cls_idx, k_of = _draws(mix, n, rng)
     pick = Picker(world, predicates, int(mix["instances_per_k"]))
     out = []
     for i in range(n):
-        q = make_query(mix, classes[cls_idx[i]], int(k_of[i]), pick, rng)
+        q = make_query(mix, mix["classes"][cls_idx[i]], int(k_of[i]), pick,
+                       rng)
         q["due"] = float(due[i])
         q["session"] = i % int(mix["sessions"])
         out.append(q)
     return out
+
+
+# queries over which a closed loop's classes and top_k follow their shares
+STREAM_BLOCK = 20
+
+
+def stream(mix: dict, seed: int, world,
+           predicates: List[str]) -> Iterator[dict]:
+    """A closed loop's queries, without end, in the order the clients take
+    them: blocks of ``STREAM_BLOCK`` queries drawn as :func:`schedule`
+    draws its window, each block's order the same for every seed, as an
+    open loop's arrival times are; the seed draws the texts."""
+    rng = np.random.default_rng([seed, 0x7AFF1C])
+    order = np.random.default_rng(0xC105ED)
+    pick = Picker(world, predicates, int(mix["instances_per_k"]))
+    while True:
+        for c, k in zip(*_draws(mix, STREAM_BLOCK, order)):
+            yield make_query(mix, mix["classes"][c], int(k), pick, rng)
 
 
 def warmup_batches(mix: dict, max_batch: int, world, predicates: List[str],
